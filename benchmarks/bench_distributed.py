@@ -1,8 +1,13 @@
-"""Sharded-engine benchmark: 1 vs N forced host devices (DESIGN.md §11).
+"""Sharded-engine parity bench on forced CPU host devices (DESIGN.md §11).
+
+A CPU bench, not a chip run: every shard is a forced host device of the
+CPU backend, all sharing the same cores, so its wall-clock columns are CPU
+numbers and never a device metric.  The sharded path on real chips is
+``chip_smoke.py --chips 4``.
 
 Runs the same clique workload on the single-device engine and on the
 sharded engine at increasing shard counts, asserting byte-identical top-k
-results at every width, then reports wall-clock speedup plus per-shard
+results at every width, then reports CPU wall-clock ratios plus per-shard
 spill / refill / rebalance stats from a skewed workload that forces the
 host-side rebalancer to move work.
 
@@ -69,7 +74,8 @@ def _bench(fast: bool) -> dict:
             spilled=res.spilled, refilled=res.refilled,
             rebalanced=res.rebalanced))
 
-    print(f"[bench_distributed] clique n={n} m={m} k={cfg.k} "
+    print(f"[bench_distributed] CPU forced host devices, not a device "
+          f"metric: clique n={n} m={m} k={cfg.k} "
           f"(parity vs single-device Engine asserted at every width)")
     print("  note: forced host devices share one CPU, so wall-clock here "
           "validates plumbing, not hardware speedup (see DESIGN.md §11)")
@@ -147,7 +153,8 @@ def _bench(fast: bool) -> dict:
     best8 = max((r["speedup"] for r in stale_rows
                  if r["shards"] == _DEVICES and r["sync_every"] > 1),
                 default=0.0)
-    print(f"[bench_distributed] stale-bound K-sweep: decoy-trap clique "
+    print(f"[bench_distributed] CPU forced host devices, not a device "
+          f"metric: stale-bound K-sweep: decoy-trap clique "
           f"n={nl} m={ml} clusters={ncl} k={lcfg.k} T={lcfg.steps_per_sync} "
           f"(parity vs single-device asserted on every row)")
     print(f"  single-device Engine.run : {base_s:.3f}s")
@@ -159,7 +166,7 @@ def _bench(fast: bool) -> dict:
               f"{r['wall_s']:>8.3f} {r['speedup']:>8.2f} {r['steps']:>6} "
               f"{r['syncs']:>6} {r['host_syncs']:>6} {r['spilled']:>7} "
               f"{r['rebalanced']:>6}")
-    print(f"  best 8-shard speedup at K>1: {best8:.2f}x")
+    print(f"  best 8-shard CPU wall-clock ratio at K>1: {best8:.2f}x")
 
     return dict(devices=_DEVICES, n=n, m=m, single_device_s=round(seq_s, 3),
                 sharded=rows, skewed=skew,

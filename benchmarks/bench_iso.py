@@ -113,12 +113,12 @@ def run_candidate_paths(n=150, m=500, n_labels=3, seed=0, batch=64,
         reps = -(-batch // states.shape[0])          # tile seeds up to batch
         block = jnp.concatenate([states] * reps)[:batch]
         step = jax.jit(comp.score_children)
-        jax.block_until_ready(step(block))           # compile + warm up
+        jax.block_until_ready(step(block, comp.tables))  # compile + warm up
         best = float("inf")                          # best-of-rounds: these
         for _ in range(rounds):                      # calls are ~0.1 ms, so
             t0 = time.perf_counter()                 # min filters scheduler
             for _ in range(repeats):                 # noise out of the mean
-                out = step(block)
+                out = step(block, comp.tables)
             jax.block_until_ready(out)
             best = min(best, (time.perf_counter() - t0) / repeats)
         ms = best * 1e3

@@ -130,6 +130,8 @@ def main():
                     help="ingest existing per-PR artifacts (BENCH_PR*.json) "
                          "into --trajectory and exit without benchmarking")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.backfill:
         rows = []

@@ -25,7 +25,11 @@ API contract (property-tested in ``tests/test_engine_properties.py``):
   (anti-monotonicity — what makes threshold pruning sound).
 
 States are fixed-width ``int32`` vectors; actions are integers in
-``[0, num_actions)``.  ``score_children`` performs *targeted expansion*: it
+``[0, num_actions)``.  The device callbacks take the computation's
+``tables`` (a pytree of graph-sized device arrays, e.g. clique's ``[N, W]``
+extension masks) as their last argument: the engine passes them into its
+jitted programs as arguments, so they are never compiled into the
+executable as constants.  ``score_children`` performs *targeted expansion*: it
 returns ``NEG`` priority for any (state, action) that must not be created,
 so irrelevant subgraphs are never materialized (contrast: Arabesque's
 exhaustive expansion + post-filter, implemented in
@@ -34,7 +38,7 @@ exhaustive expansion + post-filter, implemented in
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Optional, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,21 +57,26 @@ class SubgraphComputation:
     # () -> (states [n0, S], prio [n0], ub [n0])
     init_frontier: Callable[[], Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]]
 
-    # states [B, S] -> (child_prio [B, A], child_ub [B, A]); NEG = not expandable
-    score_children: Callable[[jnp.ndarray],
+    # (states [B, S], tables) -> (child_prio [B, A], child_ub [B, A]);
+    # NEG = not expandable
+    score_children: Callable[[jnp.ndarray, Any],
                              Tuple[jnp.ndarray, jnp.ndarray]]
 
-    # (parent_states [M, S], actions [M]) -> child states [M, S]
-    materialize: Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray]
+    # (parent_states [M, S], actions [M], tables) -> child states [M, S]
+    materialize: Callable[[jnp.ndarray, jnp.ndarray, Any], jnp.ndarray]
 
-    # states [B, S] -> result keys [B] (NEG when not relevant)
-    result_key: Callable[[jnp.ndarray], jnp.ndarray]
+    # (states [B, S], tables) -> result keys [B] (NEG when not relevant)
+    result_key: Callable[[jnp.ndarray, Any], jnp.ndarray]
 
-    # states [B, S] -> result-space upper bound [B]
-    upper_bound: Callable[[jnp.ndarray], jnp.ndarray]
+    # (states [B, S], tables) -> result-space upper bound [B]
+    upper_bound: Callable[[jnp.ndarray, Any], jnp.ndarray]
 
     # pretty-printer for result states (host-side)
     describe: Optional[Callable] = None
+
+    # pytree of device arrays the callbacks above read (the last argument
+    # of each); the engine passes it into its jitted programs
+    tables: Any = ()
 
     def __post_init__(self):
         if self.state_width <= 0:
@@ -84,44 +93,45 @@ def from_pointwise(name: str,
                    state_width: int,
                    num_actions: int,
                    init_frontier,
-                   expandable,       # (state [S], action) -> bool
-                   child_priority,   # (state [S], action) -> int32
-                   child_ub,         # (state [S], action) -> int32
-                   materialize_one,  # (state [S], action) -> state [S]
-                   relevant,         # (state [S]) -> bool
-                   result_key_one,   # (state [S]) -> int32
-                   upper_bound_one,  # (state [S]) -> int32
-                   describe=None) -> SubgraphComputation:
+                   expandable,       # (state [S], action, tables) -> bool
+                   child_priority,   # (state [S], action, tables) -> int32
+                   child_ub,         # (state [S], action, tables) -> int32
+                   materialize_one,  # (state [S], action, tables) -> [S]
+                   relevant,         # (state [S], tables) -> bool
+                   result_key_one,   # (state [S], tables) -> int32
+                   upper_bound_one,  # (state [S], tables) -> int32
+                   describe=None, tables=()) -> SubgraphComputation:
     """Succinct per-subgraph API (the paper's Listing-1 style), vmapped.
 
-    Users write scalar functions over a single state; this adapter builds the
-    batched computation via ``jax.vmap``.  The fused batched path (e.g.
-    :mod:`repro.core.clique`) is preferred for hot computations.
+    Users write scalar functions over a single state (plus the shared
+    ``tables``); this adapter builds the batched computation via
+    ``jax.vmap``.  The fused batched path (e.g. :mod:`repro.core.clique`)
+    is preferred for hot computations.
     """
     actions = jnp.arange(num_actions, dtype=jnp.int32)
 
-    def score_children(states):
+    def score_children(states, t):
         def per_state(s):
             def per_action(a):
-                ok = expandable(s, a)
-                return (jnp.where(ok, child_priority(s, a), NEG),
-                        jnp.where(ok, child_ub(s, a), NEG))
+                ok = expandable(s, a, t)
+                return (jnp.where(ok, child_priority(s, a, t), NEG),
+                        jnp.where(ok, child_ub(s, a, t), NEG))
             return jax.vmap(per_action)(actions)
         return jax.vmap(per_state)(states)
 
-    def materialize(states, acts):
-        return jax.vmap(materialize_one)(states, acts)
+    def materialize(states, acts, t):
+        return jax.vmap(lambda s, a: materialize_one(s, a, t))(states, acts)
 
-    def result_key(states):
+    def result_key(states, t):
         def one(s):
-            return jnp.where(relevant(s), result_key_one(s), NEG)
+            return jnp.where(relevant(s, t), result_key_one(s, t), NEG)
         return jax.vmap(one)(states)
 
-    def upper_bound(states):
-        return jax.vmap(upper_bound_one)(states)
+    def upper_bound(states, t):
+        return jax.vmap(lambda s: upper_bound_one(s, t))(states)
 
     return SubgraphComputation(
         name=name, state_width=state_width, num_actions=num_actions,
         init_frontier=init_frontier, score_children=score_children,
         materialize=materialize, result_key=result_key,
-        upper_bound=upper_bound, describe=describe)
+        upper_bound=upper_bound, describe=describe, tables=tables)

@@ -19,7 +19,9 @@ User functions (paper Table 1 → here):
 The child-scoring hot loop — ``popcount(P ∩ N(v) ∩ {u > v})`` for the whole
 ``[B, N]`` grid — is the compute kernel of the paper's system; it runs either
 as pure jnp (reference) or via the Pallas kernel
-:mod:`repro.kernels.frontier_expand` (``use_pallas=True``).
+:mod:`repro.kernels.frontier_expand` (``use_pallas=True``).  The ``[N, W]``
+extension masks are the computation's ``tables``: a device argument of the
+engine's jitted step, not a constant compiled into it.
 """
 from __future__ import annotations
 
@@ -49,6 +51,7 @@ def make_clique_computation(graph: GraphStore,
     adj = jnp.asarray(graph.adj_bits)                      # [N, W] uint32
     gt = jnp.asarray(bitset.lt_mask_table(n))              # [N, W] uint32
     ext_mask = adj & gt                                    # N(v) ∩ {u > v}
+    tables = dict(ext=ext_mask)
 
     if use_pallas:
         from repro.kernels import ops as kops
@@ -69,8 +72,7 @@ def make_clique_computation(graph: GraphStore,
     # ------------------------------------------------------------ callbacks
     def init_frontier():
         # unit cliques {v} with P = N(v) ∩ {u > v}  (canonical seeds)
-        v_bits = jnp.asarray(np.stack(
-            [bitset.from_indices([v], n) for v in range(n)]))
+        v_bits = jnp.asarray(bitset.eye_table(n))
         p_bits = ext_mask
         size = jnp.ones((n,), jnp.int32)
         states = _pack(v_bits, p_bits, size)
@@ -79,13 +81,13 @@ def make_clique_computation(graph: GraphStore,
         ub = size + pcount
         return states, prio, ub
 
-    def score_children(states):
+    def score_children(states, t):
         _, p_bits, size, _ = _unpack(states)
         if use_pallas:
-            counts = kops.frontier_expand(p_bits, ext_mask,
+            counts = kops.frontier_expand(p_bits, t["ext"],
                                           interpret=interpret)  # [B, N]
         else:
-            inter = p_bits[:, None, :] & ext_mask[None, :, :]
+            inter = p_bits[:, None, :] & t["ext"][None, :, :]
             counts = bitset.popcount(inter, axis=-1)         # [B, N]
         in_p = bitset.to_bool(p_bits, n)                     # expandable
         child_prio = jnp.where(in_p, (size[:, None] + 1) * (n + 1) + counts,
@@ -93,16 +95,16 @@ def make_clique_computation(graph: GraphStore,
         child_ub = jnp.where(in_p, size[:, None] + 1 + counts, NEG)
         return child_prio, child_ub
 
-    def materialize(states, actions):
+    def materialize(states, actions, t):
         v_bits, p_bits, size, _ = _unpack(states)
         new_v = bitset.set_bit(v_bits, actions)
-        new_p = p_bits & ext_mask[actions]
+        new_p = p_bits & t["ext"][actions]
         return _pack(new_v, new_p, size + 1)
 
-    def result_key(states):
+    def result_key(states, t):
         return states[:, 2 * w]          # clique size; always relevant
 
-    def upper_bound(states):
+    def upper_bound(states, t):
         return states[:, 2 * w] + states[:, 2 * w + 1]
 
     def describe(state_row: np.ndarray) -> list:
@@ -115,4 +117,4 @@ def make_clique_computation(graph: GraphStore,
         name="clique", state_width=S, num_actions=n,
         init_frontier=init_frontier, score_children=score_children,
         materialize=materialize, result_key=result_key,
-        upper_bound=upper_bound, describe=describe)
+        upper_bound=upper_bound, describe=describe, tables=tables)

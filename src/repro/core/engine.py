@@ -224,8 +224,8 @@ def merge_topk(states: jnp.ndarray, keys: jnp.ndarray, k: int):
     Candidates may contain the same (state, key) pair more than once — a
     deferred parent re-enters the pool and contributes its result key again
     on re-dequeue, and per-shard result sets can both have seen a state the
-    rebalancer moved.  Duplicates are adjacent after the lexicographic sort
-    and all but the first are demoted to empty, so one state can never
+    rebalancer moved.  All but the first occurrence of a duplicate are
+    demoted to empty, so one state can never
     occupy two result slots (which would both displace the true k-th result
     and tighten the dominance threshold unsoundly).
 
@@ -235,21 +235,39 @@ def merge_topk(states: jnp.ndarray, keys: jnp.ndarray, k: int):
     a sharded run (any shard count, any interleaving) reproduce the
     single-device result set byte-for-byte (DESIGN.md §11).  States in
     empty slots (key == NEG) are zeroed so they too are byte-stable.
+
+    The order is computed pairwise: candidates are few (``k + B`` per
+    super-step, ``shards * k`` in the bound exchange) while states are
+    wide (``S = 2W + 2`` words for clique), so comparing every pair at its
+    first differing word costs O(n²·S) elementwise work and one 1-key
+    sort.  XLA fuses the compare into the reduction, so no ``[n, n, S]``
+    array is formed; the ``[n, n]`` intermediates are what grows with k.
+    A lexicographic multi-key sort would take one sort operand per state
+    word, and its compile time grows with ``S``.  A prefix-doubling rank
+    (O(S·n·log n·log S) in batched sorts) compiled and ran slower at the
+    sizes the engine sees (PERF.md, Findings).
     """
-    s = states.shape[-1]
-    # key is the least-significant sort column so equal states cluster by
-    # key too — without it a NEG-keyed copy sorted between two real-keyed
-    # copies of the same state would hide them from the adjacency check
-    lex = jnp.lexsort((keys,) + tuple(states[:, j]
-                                      for j in reversed(range(s))))
-    ss, kk = states[lex], keys[lex]
-    dup = jnp.concatenate([
-        jnp.zeros((1,), bool),
-        jnp.all(ss[1:] == ss[:-1], axis=1) & (kk[1:] == kk[:-1])])
-    kk = jnp.where(dup, NEG, kk)
-    top = jnp.argsort(kk, stable=True, descending=True)[:k]
-    top_keys = kk[top]
-    top_states = jnp.where((top_keys > NEG)[:, None], ss[top], 0)
+    n, s = states.shape
+    # first differing word of every pair of rows (s where the rows match)
+    word = jnp.arange(s, dtype=jnp.int32)
+    first = jnp.min(jnp.where(states[:, None, :] != states[None, :, :],
+                              word, s), axis=-1)                 # [n, n]
+    same = first == s
+    # at[i, j] = states[i, first[i, j]]; first is symmetric, so at.T holds
+    # row j's word at the same position and `at < at.T` is "row i
+    # lexicographically before row j" (signed int32 order)
+    at = jnp.take_along_axis(states, jnp.minimum(first, s - 1), axis=1)
+    same_key = keys[:, None] == keys[None, :]
+    precedes = (keys[:, None] > keys[None, :]) | (same_key & (at < at.T))
+    # of identical (state, key) pairs only the first occurrence is live
+    row = jnp.arange(n)
+    dup = jnp.any(same & same_key & (row[None, :] < row[:, None]), axis=1)
+    live = (keys > NEG) & ~dup
+    # live pairs are distinct, so their ranks are 0..live-1 with no ties
+    rank = jnp.where(live, jnp.sum(live[:, None] & precedes, axis=0), n)
+    top = jnp.argsort(rank)[:k]
+    top_keys = jnp.where(rank[top] < n, keys[top], NEG)
+    top_states = jnp.where((top_keys > NEG)[:, None], states[top], 0)
     return top_states, top_keys
 
 
@@ -307,11 +325,13 @@ class Engine:
 
     # ------------------------------------------------------------------ step
     def _step_impl(self, pool_states, pool_prio, pool_ub,
-                   result_states, result_keys, bound_sync=None):
-        """One super-step.  ``bound_sync`` (None for the single-device
-        engine) maps the local result keys to the pruning threshold; the
-        sharded engine passes :func:`make_sharded_bound_sync`'s collective
-        so every shard prunes against the global k-th best (DESIGN.md §11).
+                   result_states, result_keys, tables, bound_sync=None):
+        """One super-step.  ``tables`` is the computation's device tables
+        (``comp.tables``), an argument so the graph is never compiled in.
+        ``bound_sync`` (None for the single-device engine) maps the local
+        result keys to the pruning threshold; the sharded engine passes
+        :func:`make_sharded_bound_sync`'s collective so every shard prunes
+        against the global k-th best (DESIGN.md §11).
         """
         comp, B, M, C, k = self.comp, self.B, self.M, self.C, self.k
         A = comp.num_actions
@@ -324,7 +344,7 @@ class Engine:
         pool_prio = pool_prio.at[idx_b].set(NEG)
 
         # 2. result insertion (Alg. 1 lines 6-10), canonical tie-break
-        rkey_b = jnp.where(valid_b, comp.result_key(states_b), NEG)
+        rkey_b = jnp.where(valid_b, comp.result_key(states_b, tables), NEG)
         merged_keys = jnp.concatenate([result_keys, rkey_b])
         merged_states = jnp.concatenate([result_states, states_b])
         result_states, result_keys = merge_topk(merged_states, merged_keys, k)
@@ -340,7 +360,7 @@ class Engine:
         pruned = jnp.sum(valid_b & ~expand_b)
 
         # 4. targeted expansion: score the [B, A] child grid
-        child_prio, child_ub = comp.score_children(states_b)
+        child_prio, child_ub = comp.score_children(states_b, tables)
         keep = expand_b[:, None] & (child_prio > NEG) & (child_ub >= threshold)
 
         # greedy parent admission: expand parents (already sorted by priority)
@@ -356,7 +376,8 @@ class Engine:
         sel_valid = top_cp > NEG
         sel_parent = top_ci // A
         sel_action = (top_ci % A).astype(jnp.int32)
-        child_states = comp.materialize(states_b[sel_parent], sel_action)
+        child_states = comp.materialize(states_b[sel_parent], sel_action,
+                                       tables)
         child_states = jnp.where(sel_valid[:, None], child_states, 0)
         child_ub_sel = jnp.where(
             sel_valid, child_ub.reshape(B * A)[top_ci], NEG)
@@ -388,8 +409,8 @@ class Engine:
 
     # ------------------------------------------------------------ macro-step
     def _macro_impl(self, pool_states, pool_prio, pool_ub,
-                    result_states, result_keys, t_max, vpq_nonempty, occ0,
-                    bound_sync=None, any_reduce=None, sync_every=1,
+                    result_states, result_keys, tables, t_max, vpq_nonempty,
+                    occ0, bound_sync=None, any_reduce=None, sync_every=1,
                     stale_sync=None, record_bounds=False):
         """Up to ``t_max`` fused super-steps in one ``lax.while_loop``
         (DESIGN.md §13).  Per-step overflow blocks land in a fixed
@@ -435,10 +456,10 @@ class Engine:
         if sync_every <= 1 and not record_bounds:
             return self._macro_flat(
                 pool_states, pool_prio, pool_ub, result_states, result_keys,
-                t_max, vpq_nonempty, occ0, bound_sync, any_reduce)
+                tables, t_max, vpq_nonempty, occ0, bound_sync, any_reduce)
         return self._macro_segmented(
             pool_states, pool_prio, pool_ub, result_states, result_keys,
-            t_max, vpq_nonempty, occ0, bound_sync, any_reduce,
+            tables, t_max, vpq_nonempty, occ0, bound_sync, any_reduce,
             max(1, sync_every), stale_sync, record_bounds)
 
     def _cont_flag(self, seg_blocks, vpq_nonempty, any_reduce,
@@ -466,12 +487,12 @@ class Engine:
                 any_reduce(active)
         return (t_next < t_max) & cont
 
-    def _fused_step(self, ps, pp, pu, rs, rk, acc_s, acc_p, acc_u, w, sums,
-                    sync_fn):
+    def _fused_step(self, ps, pp, pu, rs, rk, tables, acc_s, acc_p, acc_u,
+                    w, sums, sync_fn):
         """One inner super-step plus overflow-accumulator/stat packing —
         the body both macro variants repeat."""
         ps, pp, pu, rs, rk, (o_s, o_p, o_u), stats = self._step_impl(
-            ps, pp, pu, rs, rk, bound_sync=sync_fn)
+            ps, pp, pu, rs, rk, tables, bound_sync=sync_fn)
         cnt = jnp.sum(o_p > NEG).astype(jnp.int32)
         acc_s = jax.lax.dynamic_update_slice(acc_s, o_s, (w, 0))
         acc_p = jax.lax.dynamic_update_slice(acc_p, o_p, (w,))
@@ -482,8 +503,8 @@ class Engine:
         return ps, pp, pu, rs, rk, acc_s, acc_p, acc_u, w, sums, stats
 
     def _macro_flat(self, pool_states, pool_prio, pool_ub,
-                    result_states, result_keys, t_max, vpq_nonempty, occ0,
-                    bound_sync, any_reduce):
+                    result_states, result_keys, tables, t_max, vpq_nonempty,
+                    occ0, bound_sync, any_reduce):
         """The ``sync_every == 1`` macro loop: one step per iteration, the
         §4 exchange (when sharded) and the exit vote every inner step."""
         S, cap = self.S, self.acc_cap
@@ -494,8 +515,8 @@ class Engine:
             (t, ps, pp, pu, rs, rk, acc_s, acc_p, acc_u, w, sums, _occ,
              _thr, _cont) = carry
             (ps, pp, pu, rs, rk, acc_s, acc_p, acc_u, w, sums, stats) = \
-                self._fused_step(ps, pp, pu, rs, rk, acc_s, acc_p, acc_u,
-                                 w, sums, bound_sync)
+                self._fused_step(ps, pp, pu, rs, rk, tables, acc_s, acc_p,
+                                 acc_u, w, sums, bound_sync)
             occ = stats["pool_occupancy"]
             return (t + 1, ps, pp, pu, rs, rk, acc_s, acc_p, acc_u, w,
                     sums, occ, stats["threshold"],
@@ -517,9 +538,9 @@ class Engine:
         return ps, pp, pu, rs, rk, acc_s, acc_p, acc_u, stats
 
     def _macro_segmented(self, pool_states, pool_prio, pool_ub,
-                         result_states, result_keys, t_max, vpq_nonempty,
-                         occ0, bound_sync, any_reduce, sync_every,
-                         stale_sync, record_bounds):
+                         result_states, result_keys, tables, t_max,
+                         vpq_nonempty, occ0, bound_sync, any_reduce,
+                         sync_every, stale_sync, record_bounds):
         """The ``sync_every = K > 1`` macro loop (DESIGN.md §14): each
         iteration runs one K-step segment — fresh exchange at the head,
         stale-bound tail, one vote at the boundary.  Collective-free when
@@ -543,8 +564,8 @@ class Engine:
             # segment head: the fresh §4 exchange becomes this segment's
             # carried global bound
             (ps, pp, pu, rs, rk, acc_s, acc_p, acc_u, w, sums, stats) = \
-                self._fused_step(ps, pp, pu, rs, rk, acc_s, acc_p, acc_u,
-                                 w, sums, bound_sync)
+                self._fused_step(ps, pp, pu, rs, rk, tables, acc_s, acc_p,
+                                 acc_u, w, sums, bound_sync)
             stale = stats["threshold"]
             occ = stats["pool_occupancy"]
             if record_bounds:
@@ -565,8 +586,9 @@ class Engine:
                     return used
 
                 (ps, pp, pu, rs, rk, acc_s, acc_p, acc_u, w, sums,
-                 stats) = self._fused_step(ps, pp, pu, rs, rk, acc_s,
-                                           acc_p, acc_u, w, sums, sync_fn)
+                 stats) = self._fused_step(ps, pp, pu, rs, rk, tables,
+                                           acc_s, acc_p, acc_u, w, sums,
+                                           sync_fn)
                 if record_bounds:
                     tr_u = tr_u.at[t_i].set(box["used"])
                     tr_f = tr_f.at[t_i].set(box["fresh"])
@@ -681,7 +703,7 @@ class Engine:
                      st.result_states, st.result_keys, overflow,
                      stats) = self._step(
                         st.pool_states, st.pool_prio, st.pool_ub,
-                        st.result_states, st.result_keys)
+                        st.result_states, st.result_keys, self.comp.tables)
                 with self._span("engine.host_sync"):
                     stats = jax.tree.map(int, jax.device_get(stats))
                 st.steps += 1
@@ -704,7 +726,7 @@ class Engine:
                  st.result_states, st.result_keys, acc_s, acc_p, acc_u,
                  stats) = self._macro(
                     st.pool_states, st.pool_prio, st.pool_ub,
-                    st.result_states, st.result_keys,
+                    st.result_states, st.result_keys, self.comp.tables,
                     np.int32(t_cap), len(st.vpq) > 0,
                     np.int32(st.pool_occupancy))
             with self._span("engine.host_sync"):

@@ -33,6 +33,21 @@ import jax.numpy as jnp
 from . import bitset
 
 
+def gather_csr(indptr: np.ndarray, indices: np.ndarray, vs: np.ndarray):
+    """All (row, neighbor, CSR slot) triples for source vertices ``vs`` of
+    the CSR ``indptr``/``indices``, vectorized: ``row`` indexes ``vs``,
+    and the slot maps each pair back to per-slot data such as
+    ``edge_labels``."""
+    starts = indptr[vs].astype(np.int64)
+    counts = indptr[np.asarray(vs) + 1].astype(np.int64) - starts
+    total = int(counts.sum())
+    rows = np.repeat(np.arange(len(counts), dtype=np.int64), counts)
+    offset = np.arange(total, dtype=np.int64) - \
+        np.repeat(np.cumsum(counts) - counts, counts)
+    slots = np.repeat(starts, counts) + offset
+    return rows, indices[slots], slots
+
+
 @dataclasses.dataclass(frozen=True)
 class GraphStore:
     n: int                               # number of vertices

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 
 from . import bitset
 from .api import NEG, SubgraphComputation
-from .graph import GraphStore
+from .graph import GraphStore, gather_csr
 from .labels import LABEL_FILTERS, LabelPredicate
 
 
@@ -46,9 +46,12 @@ def build_iso_index(graph: GraphStore, max_hops: int,
     """``index[v, l, h]`` = max degree over label-l vertices exactly h hops
     from v (h in 1..max_hops; h index 0 is hop 1).  Shape [N, L, H].
 
-    Built with dense boolean matmuls (device) — the paper notes index
-    construction is embarrassingly parallel; here one matmul per hop does
-    all vertices at once.
+    Built host-side by sparse frontier expansion: each hop extends every
+    (source, vertex) pair of the last level over the CSR adjacency, so the
+    work is the number of pairs within ``max_hops`` (about ``N·d^h`` on a
+    degree-``d`` graph), never the ``N×N`` of a dense reachability matrix
+    — which would not fit a chip's memory at the widths the bitset layout
+    serves.
 
     When a predicate restricts edge types (``edge_any_of``), hop
     reachability must be computed on the *restricted* adjacency — full-
@@ -65,26 +68,30 @@ def build_iso_index(graph: GraphStore, max_hops: int,
     assert graph.labels is not None, "iso index requires a labeled graph"
     n = graph.n
     n_labels = int(graph.labels.max()) + 1
-    adj = jnp.zeros((n, n), jnp.float32)
-    ea = graph.edge_array
+    indptr, nbr = graph.indptr, graph.indices
     if predicate is not None and predicate.edge_any_of is not None:
-        ea = ea[predicate.edge_mask_csr(graph)]
-    adj = adj.at[ea[:, 0], ea[:, 1]].set(1.0)
-    deg = jnp.asarray(graph.degrees, jnp.float32)
-    labels = np.asarray(graph.labels)
+        # the CSR restricted to the allowed slots, rows kept in place
+        keep = predicate.edge_mask_csr(graph)
+        indptr = np.concatenate([[0], np.cumsum(keep)])[indptr]
+        nbr = nbr[keep]
+    deg = graph.degrees.astype(np.int32)
+    labels = np.asarray(graph.labels, np.int64)
 
     index = np.zeros((n, n_labels, max_hops), np.int32)
-    reached = jnp.eye(n, dtype=jnp.float32)           # vertices within h-1 hops
-    frontier = jnp.eye(n, dtype=jnp.float32)
+    # (source, vertex) pairs as sorted keys source * n + vertex
+    reached = np.arange(n, dtype=np.int64) * (n + 1)   # within h-1 hops
+    frontier = reached
     for h in range(max_hops):
-        nxt = (frontier @ adj > 0).astype(jnp.float32)
-        level = jnp.clip(nxt - reached, 0.0, 1.0)     # exactly h+1 hops away
-        reached = jnp.clip(reached + nxt, 0.0, 1.0)
-        frontier = level
-        level_np = np.asarray(level)
-        for l in range(n_labels):
-            degl = np.where(labels == l, np.asarray(deg), 0.0)
-            index[:, l, h] = (level_np * degl[None, :]).max(axis=1)
+        src, u = np.divmod(frontier, n)
+        rows, v, _ = gather_csr(indptr, nbr, u)
+        reach = np.unique(src[rows] * n + v)
+        level = np.setdiff1d(reach, reached, assume_unique=True)
+        reached = np.union1d(reached, level)
+        frontier = level                               # exactly h+1 hops
+        src, u = np.divmod(level, n)
+        best = np.zeros(n * n_labels, np.int32)
+        np.maximum.at(best, src * n_labels + labels[u], deg[u])
+        index[:, :, h] = best.reshape(n, n_labels)
     return index
 
 
@@ -243,16 +250,17 @@ def make_iso_computation(graph: GraphStore,
         np.bitwise_or.reduce(graph.label_bits[list(cls)], axis=0)
         for cls in classes_o])                              # [nq, W]
 
-    deg = jnp.asarray(graph.degrees, jnp.int32)
-    adj_bits = jnp.asarray(adjc)
-    class_bits_d = jnp.asarray(class_bits)
-    ub_rest_d = jnp.asarray(ub_rest, jnp.int32)
+    # graph-sized device tables: passed into the engine's jitted programs
+    # as arguments (SubgraphComputation.tables), never closed over
+    tables = dict(deg=jnp.asarray(graph.degrees, jnp.int32),
+                  adj=jnp.asarray(adjc),
+                  class_bits=jnp.asarray(class_bits),
+                  ub_rest=jnp.asarray(ub_rest, jnp.int32),
+                  eye=jnp.asarray(bitset.eye_table(n)))
+    if allowed_vbits is not None:
+        tables["allowed_vbits"] = jnp.asarray(allowed_vbits)
+        tables["allowed_vmask"] = jnp.asarray(allowed_vmask)
     q_adj_d = jnp.asarray(q_adj_o)
-    eye_bits = jnp.asarray(bitset.eye_table(n))
-    allowed_vbits_d = (jnp.asarray(allowed_vbits)
-                       if allowed_vbits is not None else None)
-    allowed_vmask_d = (jnp.asarray(allowed_vmask)
-                       if allowed_vmask is not None else None)
     if use_pallas:
         from repro.kernels import ops as kops
 
@@ -262,7 +270,7 @@ def make_iso_computation(graph: GraphStore,
 
     full_word = jnp.uint32(0xFFFFFFFF)
 
-    def _cand_parts(states):
+    def _cand_parts(states, t):
         """Batched candidate generation for a whole dequeued batch: per-row
         label bitsets and constraint masks (adjacency/complement products
         ∧ ~used), one gather + AND-reduce instead of a per-state loop.
@@ -280,41 +288,41 @@ def make_iso_computation(graph: GraphStore,
         mapping = states[:, :nq]                        # [B, nq]
         d = states[:, nq]                               # [B]
         j = jnp.minimum(d, nq - 1)
-        lbl = class_bits_d[j]                           # [B, W]
-        if pushdown and allowed_vbits_d is not None:
+        lbl = t["class_bits"][j]                        # [B, W]
+        if pushdown and "allowed_vbits" in t:
             # predicate pushdown: the allowed-vertex bitset seeds the
             # per-row kernel mask, so label-infeasible candidates are
             # culled inside the masked intersection (DESIGN.md §12)
-            mask = jnp.broadcast_to(allowed_vbits_d, (b, w))
+            mask = jnp.broadcast_to(t["allowed_vbits"], (b, w))
         else:
             mask = jnp.full((b, w), full_word)
         used = jnp.zeros((b, w), jnp.uint32)
         for i in range(nq):                             # static: nq small
             mi = jnp.maximum(mapping[:, i], 0)          # [B]
-            row = adj_bits[mi]                          # [B, W]
+            row = t["adj"][mi]                          # [B, W]
             need = q_adj_d[i][j]                        # [B] (q_adj symmetric)
             con = jnp.where(need[:, None], row, ~row) if induced else \
                 jnp.where(need[:, None], row, full_word)
             active = (i < d)[:, None]                   # [B, 1]
             mask = jnp.where(active, mask & con, mask)
-            used = jnp.where(active, used | eye_bits[mi], used)
+            used = jnp.where(active, used | t["eye"][mi], used)
         mask = mask & ~used
         return lbl, jnp.where((d < nq)[:, None], mask, jnp.uint32(0))
 
-    def _cand_bits(state):
+    def _cand_bits(state, t):
         """Per-state loop form of :func:`_cand_parts` (legacy reference,
         kept for the `cand_path="vmap"/"map"` benchmark baselines)."""
         mapping = state[:nq]
         d = state[nq]
         j = jnp.minimum(d, nq - 1)
-        acc = class_bits_d[j]
-        if pushdown and allowed_vbits_d is not None:
-            acc = acc & allowed_vbits_d
+        acc = t["class_bits"][j]
+        if pushdown and "allowed_vbits" in t:
+            acc = acc & t["allowed_vbits"]
 
         def body(i, carry):
             acc, used = carry
             mi = jnp.maximum(mapping[i], 0)
-            row = adj_bits[mi]
+            row = t["adj"][mi]
             need = q_adj_d[i, j]
             constraint = jnp.where(need, row, ~row) if induced else \
                 jnp.where(need, row, jnp.uint32(0xFFFFFFFF))
@@ -349,53 +357,53 @@ def make_iso_computation(graph: GraphStore,
         return (jnp.asarray(states), jnp.asarray(prio, jnp.int32),
                 jnp.asarray(ub, jnp.int32))
 
-    def score_children(states):
+    def score_children(states, t):
         if use_pallas:
-            lbl, mask = _cand_parts(states)
+            lbl, mask = _cand_parts(states, t)
             in_cand = kops.masked_intersect(
-                lbl, eye_bits, mask, interpret=interpret) > 0    # [B, N]
+                lbl, t["eye"], mask, interpret=interpret) > 0    # [B, N]
         elif cand_path == "batched":
-            lbl, mask = _cand_parts(states)
+            lbl, mask = _cand_parts(states, t)
             in_cand = bitset.to_bool(lbl & mask, n)              # [B, N]
         elif cand_path == "vmap":
-            cand = jax.vmap(_cand_bits)(states)                  # [B, W]
+            cand = jax.vmap(lambda s: _cand_bits(s, t))(states)  # [B, W]
             in_cand = bitset.to_bool(cand, n)                    # [B, N]
         else:  # "map": one state at a time (the pre-batching loop form)
-            cand = jax.lax.map(_cand_bits, states)               # [B, W]
+            cand = jax.lax.map(lambda s: _cand_bits(s, t), states)
             in_cand = bitset.to_bool(cand, n)                    # [B, N]
-        if not pushdown and allowed_vmask_d is not None:
+        if not pushdown and "allowed_vmask" in t:
             # host-side-filter baseline: the unconstrained candidate grid
             # was materialized above; the predicate lands only now
-            in_cand = in_cand & allowed_vmask_d[None, :]
+            in_cand = in_cand & t["allowed_vmask"][None, :]
         d = states[:, nq]
         score = states[:, nq + 1]
         seed = jnp.maximum(states[:, 0], 0)
         nd = jnp.minimum(d + 1, nq)
-        rest = ub_rest_d[seed, nd]                           # [B]
-        child_score = score[:, None] + deg[None, :]
+        rest = t["ub_rest"][seed, nd]                        # [B]
+        child_score = score[:, None] + t["deg"][None, :]
         child_ub = child_score + rest[:, None]
         child_prio = nd[:, None] * base + child_ub
         invalid = ~in_cand
         return (jnp.where(invalid, NEG, child_prio),
                 jnp.where(invalid, NEG, child_ub))
 
-    def materialize(states, actions):
+    def materialize(states, actions, t):
         d = states[:, nq]
         b = states.shape[0]
         row = jnp.arange(b)
         out = states.at[row, d].set(actions)
         out = out.at[row, nq].add(1)
-        out = out.at[row, nq + 1].add(deg[actions])
+        out = out.at[row, nq + 1].add(t["deg"][actions])
         return out
 
-    def result_key(states):
+    def result_key(states, t):
         complete = states[:, nq] == nq
         return jnp.where(complete, states[:, nq + 1], NEG)
 
-    def upper_bound(states):
+    def upper_bound(states, t):
         d = states[:, nq]
         seed = jnp.maximum(states[:, 0], 0)
-        return states[:, nq + 1] + ub_rest_d[seed, jnp.minimum(d, nq)]
+        return states[:, nq + 1] + t["ub_rest"][seed, jnp.minimum(d, nq)]
 
     def describe(state_row: np.ndarray) -> list:
         m = list(map(int, state_row[:nq]))
@@ -405,4 +413,4 @@ def make_iso_computation(graph: GraphStore,
         name="iso", state_width=S, num_actions=n,
         init_frontier=init_frontier, score_children=score_children,
         materialize=materialize, result_key=result_key,
-        upper_bound=upper_bound, describe=describe)
+        upper_bound=upper_bound, describe=describe, tables=tables)

@@ -24,7 +24,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from . import bitset
-from .graph import GraphStore
+from .graph import GraphStore, gather_csr
 from .labels import LABEL_FILTERS, LabelPredicate
 
 Code = Tuple[Tuple[int, int, int, int], ...]   # ((i, j, li, lj), ...)
@@ -244,23 +244,6 @@ def _edge_probe(g: GraphStore, u: np.ndarray, v: np.ndarray,
     return np.asarray(counts[:e, 0]) > 0
 
 
-def _gather_neighbors(g: GraphStore, vs: np.ndarray):
-    """All (row, neighbor, CSR slot) triples for sources ``vs`` — fully
-    vectorized CSR.  The slot index maps each pair back to its
-    ``edge_labels`` entry (edge-type filtering)."""
-    counts = g.degrees[vs].astype(np.int64)
-    total = int(counts.sum())
-    if total == 0:
-        return (np.zeros(0, np.int64), np.zeros(0, np.int32),
-                np.zeros(0, np.int64))
-    rows = np.repeat(np.arange(len(vs), dtype=np.int64), counts)
-    starts = g.indptr[vs].astype(np.int64)
-    offset = np.arange(total, dtype=np.int64) - \
-        np.repeat(np.cumsum(counts) - counts, counts)
-    slots = np.repeat(starts, counts) + offset
-    return rows, g.indices[slots], slots
-
-
 def seed_groups(g: GraphStore,
                 predicate: Optional[LabelPredicate] = None
                 ) -> Dict[Code, PatternGroup]:
@@ -364,7 +347,7 @@ def expand_group(g: GraphStore, group: PatternGroup,
     allowed_lw = (set(predicate.vertex_any_of)
                   if vmask is not None else None)
     for i in rmpath:
-        rows, nbr, slots = _gather_neighbors(g, emb[:, i])
+        rows, nbr, slots = gather_csr(g.indptr, g.indices, emb[:, i])
         if len(rows) == 0:
             continue
         if emask is not None:             # edge-type restriction: structural

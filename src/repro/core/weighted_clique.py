@@ -34,19 +34,18 @@ def make_weighted_clique_computation(graph: GraphStore,
     adj = jnp.asarray(graph.adj_bits)
     gt = jnp.asarray(bitset.lt_mask_table(n))
     ext_mask = adj & gt
-    wts = jnp.asarray(weights)
-    # weight of a packed bitset via per-word unpack-dot
-    wt_table = jnp.asarray(weights, jnp.int32)
+    wts = jnp.asarray(weights, jnp.int32)
+    tables = dict(ext=ext_mask, w=wts)
 
-    def _set_weight(bits):
-        return jnp.sum(jnp.where(bitset.to_bool(bits, n), wt_table, 0))
+    def _set_weight(bits, wt):
+        # weight of a packed bitset via per-word unpack-dot
+        return jnp.sum(jnp.where(bitset.to_bool(bits, n), wt, 0))
 
     def init_frontier():
-        v_bits = jnp.asarray(np.stack(
-            [bitset.from_indices([v], n) for v in range(n)]))
+        v_bits = jnp.asarray(bitset.eye_table(n))
         p_bits = ext_mask
         wv = wts
-        wp = jax.vmap(_set_weight)(p_bits)
+        wp = jax.vmap(lambda b: _set_weight(b, wts))(p_bits)
         states = jnp.concatenate(
             [bitset.to_i32(v_bits), bitset.to_i32(p_bits),
              wv[:, None], wp[:, None]], axis=-1)
@@ -57,33 +56,33 @@ def make_weighted_clique_computation(graph: GraphStore,
         return (bitset.to_u32(s[:w]), bitset.to_u32(s[w:2 * w]),
                 s[2 * w], s[2 * w + 1])
 
-    def expandable(s, a):
+    def expandable(s, a, t):
         _, p, _, _ = _unpack(s)
         return bitset.get_bit(p[None], jnp.asarray([a]))[0]
 
-    def child_priority(s, a):
+    def child_priority(s, a, t):
         _, p, wv, _ = _unpack(s)
-        new_p = p & ext_mask[a]
-        return wv + wts[a] + _set_weight(new_p)
+        new_p = p & t["ext"][a]
+        return wv + t["w"][a] + _set_weight(new_p, t["w"])
 
-    def child_ub(s, a):          # same space: weight is the result metric
-        return child_priority(s, a)
+    def child_ub(s, a, t):       # same space: weight is the result metric
+        return child_priority(s, a, t)
 
-    def materialize_one(s, a):
+    def materialize_one(s, a, t):
         v, p, wv, _ = _unpack(s)
         new_v = bitset.set_bit(v[None], jnp.asarray([a]))[0]
-        new_p = p & ext_mask[a]
+        new_p = p & t["ext"][a]
         return jnp.concatenate(
             [bitset.to_i32(new_v), bitset.to_i32(new_p),
-             (wv + wts[a])[None], _set_weight(new_p)[None]])
+             (wv + t["w"][a])[None], _set_weight(new_p, t["w"])[None]])
 
-    def relevant(s):
+    def relevant(s, t):
         return jnp.bool_(True)   # every expansion is a clique
 
-    def result_key_one(s):
+    def result_key_one(s, t):
         return s[2 * w]          # w(V)
 
-    def upper_bound_one(s):
+    def upper_bound_one(s, t):
         return s[2 * w] + s[2 * w + 1]   # w(V) + w(P): dominated() bound
 
     def describe(row):
@@ -97,7 +96,7 @@ def make_weighted_clique_computation(graph: GraphStore,
         child_priority=child_priority, child_ub=child_ub,
         materialize_one=materialize_one, relevant=relevant,
         result_key_one=result_key_one, upper_bound_one=upper_bound_one,
-        describe=describe)
+        describe=describe, tables=tables)
 
 
 def brute_force_max_weight_clique(graph: GraphStore, weights: np.ndarray):

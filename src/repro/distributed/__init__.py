@@ -1,5 +1,4 @@
 """Sharded multi-device discovery engine (DESIGN.md §11)."""
-from .sharded_engine import (ShardedEngine, ShardedEngineState,
-                             shard_map_compat)
+from .sharded_engine import ShardedEngine, ShardedEngineState
 
-__all__ = ["ShardedEngine", "ShardedEngineState", "shard_map_compat"]
+__all__ = ["ShardedEngine", "ShardedEngineState"]

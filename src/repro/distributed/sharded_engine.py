@@ -65,8 +65,9 @@ all-gather *and* the exit votes) drop by a factor of K.
 
 Label-constrained computations (DESIGN.md §12) thread through unchanged:
 the predicate's bitsets — class rows, allowed-vertex mask, restricted
-adjacency — are closure constants of ``score_children``, replicated to
-every shard exactly like the adjacency itself, so the sharded engine needs
+adjacency — are entries of the computation's ``tables``, placed once on
+every device of the mesh (replicated) and passed into the jitted
+shard_map exactly like the adjacency itself, so the sharded engine needs
 no label-specific code and the §11 byte-parity argument covers labeled
 runs verbatim (asserted in ``tests/test_labeled.py``).
 """
@@ -80,7 +81,7 @@ from typing import List, Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.api import NEG, SubgraphComputation
 from repro.core.engine import (Engine, EngineConfig, EngineResult,
@@ -89,22 +90,6 @@ from repro.core.engine import (Engine, EngineConfig, EngineResult,
                                make_stale_bound_sync, merge_topk)
 from repro.core.vpq import VirtualPriorityQueue
 
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` without replication checking, across jax versions:
-    ``jax.shard_map(check_vma=)`` (newest), ``jax.shard_map(check_rep=)``,
-    or ``jax.experimental.shard_map`` (jax 0.4.x, where the experimental
-    module is the only home and ``jax.shard_map`` does not exist)."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
 
 _STAT_KEYS = ("dequeued", "expanded", "created", "pruned",
               "pool_occupancy", "threshold")
@@ -168,6 +153,11 @@ class ShardedEngine:
                 f"XLA_FLAGS=--xla_force_host_platform_device_count=N "
                 f"or lower `shards`")
         self.mesh = Mesh(np.asarray(devices[:self.shards]), ("data",))
+        # host blocks go straight to their shards (never all to device 0);
+        # graph tables are replicated onto every shard once, not per call
+        self._data = NamedSharding(self.mesh, P("data"))
+        self._tables = jax.device_put(comp.tables,
+                                      NamedSharding(self.mesh, P()))
 
         # staleness-tolerant bound exchange (DESIGN.md §14): K inner steps
         # per §4 all-gather.  K is clamped so one K-step segment's overflow
@@ -210,25 +200,27 @@ class ShardedEngine:
         sync = make_sharded_bound_sync("data", self.k)
         spec = P("data")
 
-        def body(pool_states, pool_prio, pool_ub, result_states, result_keys):
+        def body(pool_states, pool_prio, pool_ub, result_states, result_keys,
+                 tables):
             (pool_states, pool_prio, pool_ub, result_states, result_keys,
              overflow, stats) = self._eng._step_impl(
                 pool_states, pool_prio, pool_ub, result_states, result_keys,
-                bound_sync=sync)
+                tables, bound_sync=sync)
             # scalar per-shard stats -> [1] so the mesh axis can concatenate
             stats = {name: stats[name].reshape(1) for name in _STAT_KEYS}
             return (pool_states, pool_prio, pool_ub, result_states,
                     result_keys, overflow, stats)
 
-        self._step_sharded = jax.jit(shard_map_compat(
-            body, mesh=self.mesh, in_specs=(spec,) * 5,
+        self._step_sharded = jax.jit(jax.shard_map(
+            body, mesh=self.mesh, in_specs=(spec,) * 5 + (P(),),
             out_specs=((spec,) * 5 + ((spec, spec, spec),
-                                      {name: spec for name in _STAT_KEYS}))))
+                                      {name: spec for name in _STAT_KEYS})),
+            check_vma=False))
         # refill / rebalance blocks enter through the same merge-sort insert
         # as overflow handling, one fixed [shards*C] block per call
-        self._insert_sharded = jax.jit(shard_map_compat(
+        self._insert_sharded = jax.jit(jax.shard_map(
             self._eng._insert_impl, mesh=self.mesh, in_specs=(spec,) * 6,
-            out_specs=(spec,) * 6))
+            out_specs=(spec,) * 6, check_vma=False))
 
         # fused macro-step (DESIGN.md §13/§14): the per-shard while_loop
         # with the §4 threshold collective at segment heads (every step at
@@ -246,12 +238,12 @@ class ShardedEngine:
                 return jax.lax.psum(flag.astype(jnp.int32), "data") > 0
 
             def macro_body(pool_states, pool_prio, pool_ub,
-                           result_states, result_keys, t_max, vpq_flag,
-                           occ0):
+                           result_states, result_keys, tables, t_max,
+                           vpq_flag, occ0):
                 (ps, pp, pu, rs, rk, acc_s, acc_p, acc_u, stats) = \
                     self._eng._macro_impl(
                         pool_states, pool_prio, pool_ub,
-                        result_states, result_keys, t_max,
+                        result_states, result_keys, tables, t_max,
                         vpq_flag[0], occ0[0],
                         bound_sync=sync, any_reduce=any_reduce,
                         sync_every=self.K, stale_sync=stale,
@@ -264,11 +256,12 @@ class ShardedEngine:
                          for name in stat_keys}
                 return ps, pp, pu, rs, rk, acc_s, acc_p, acc_u, stats
 
-            self._macro_sharded = jax.jit(shard_map_compat(
+            self._macro_sharded = jax.jit(jax.shard_map(
                 macro_body, mesh=self.mesh,
-                in_specs=(spec,) * 5 + (P(), spec, spec),
+                in_specs=(spec,) * 5 + (P(), P(), spec, spec),
                 out_specs=((spec,) * 8 +
-                           ({name: spec for name in stat_keys},))),
+                           ({name: spec for name in stat_keys},)),
+                check_vma=False),
                 donate_argnums=donatable_pool_argnums())
 
     # ----------------------------------------------------------------- start
@@ -307,12 +300,16 @@ class ShardedEngine:
             if len(p_i) > m:   # more seeds than per-shard pool slots
                 vpqs[i].maybe_push(s_i[m:], p_i[m:], u_i[m:])
 
+        pool_states, pool_prio, pool_ub, result_states, result_keys = \
+            jax.device_put((pool_states.reshape(shards * C, S),
+                            pool_prio.reshape(shards * C),
+                            pool_ub.reshape(shards * C),
+                            np.zeros((shards * k, S), np.int32),
+                            np.full((shards * k,), NEG, np.int32)),
+                           self._data)
         return ShardedEngineState(
-            pool_states=jnp.asarray(pool_states.reshape(shards * C, S)),
-            pool_prio=jnp.asarray(pool_prio.reshape(shards * C)),
-            pool_ub=jnp.asarray(pool_ub.reshape(shards * C)),
-            result_states=jnp.zeros((shards * k, S), jnp.int32),
-            result_keys=jnp.full((shards * k,), NEG, jnp.int32),
+            pool_states=pool_states, pool_prio=pool_prio, pool_ub=pool_ub,
+            result_states=result_states, result_keys=result_keys,
             vpqs=vpqs, pool_occupancy=occ, candidates=int(n0))
 
     # ------------------------------------------------------------------ step
@@ -333,7 +330,7 @@ class ShardedEngine:
                      st.result_states, st.result_keys, overflow,
                      stats) = self._step_sharded(
                         st.pool_states, st.pool_prio, st.pool_ub,
-                        st.result_states, st.result_keys)
+                        st.result_states, st.result_keys, self._tables)
                 with self._span("engine.host_sync"):
                     stats = jax.device_get(stats)  # each value: [shards]
                     o_s, o_p, o_u = (np.asarray(a) for a in overflow)
@@ -364,7 +361,8 @@ class ShardedEngine:
                  st.result_states, st.result_keys, acc_s, acc_p, acc_u,
                  stats) = self._macro_sharded(
                     st.pool_states, st.pool_prio, st.pool_ub,
-                    st.result_states, st.result_keys, np.int32(t_cap),
+                    st.result_states, st.result_keys, self._tables,
+                    np.int32(t_cap),
                     np.asarray([len(v) > 0 for v in st.vpqs]),
                     st.pool_occupancy.astype(np.int32))
             with self._span("engine.host_sync"):
@@ -473,9 +471,10 @@ class ShardedEngine:
             (st.pool_states, st.pool_prio, st.pool_ub, ov_s, ov_p, ov_u) = \
                 self._insert_sharded(
                     st.pool_states, st.pool_prio, st.pool_ub,
-                    jnp.asarray(blk_s.reshape(shards * C, S)),
-                    jnp.asarray(blk_p.reshape(shards * C)),
-                    jnp.asarray(blk_u.reshape(shards * C)))
+                    *jax.device_put((blk_s.reshape(shards * C, S),
+                                     blk_p.reshape(shards * C),
+                                     blk_u.reshape(shards * C)),
+                                    self._data))
             # occ + fill <= C by construction, so the insert overflow is
             # all-NEG padding; push defensively anyway
             ov_s, ov_p, ov_u = (np.asarray(a) for a in (ov_s, ov_p, ov_u))
@@ -582,13 +581,12 @@ class ShardedEngine:
                 spill_dir=sub, obs=self.obs))
         scalars = dict(extra["scalars"])
         occ = np.asarray(scalars.pop("pool_occupancy"), np.int64)
-        return ShardedEngineState(
-            pool_states=jnp.asarray(tree["pool_states"]),
-            pool_prio=jnp.asarray(tree["pool_prio"]),
-            pool_ub=jnp.asarray(tree["pool_ub"]),
-            result_states=jnp.asarray(tree["result_states"]),
-            result_keys=jnp.asarray(tree["result_keys"]),
-            vpqs=vpqs, pool_occupancy=occ, **scalars)
+        arrays = jax.device_put(
+            {name: tree[name] for name in ("pool_states", "pool_prio",
+                                           "pool_ub", "result_states",
+                                           "result_keys")}, self._data)
+        return ShardedEngineState(vpqs=vpqs, pool_occupancy=occ,
+                                  **arrays, **scalars)
 
     # ------------------------------------------------------------------- run
     def run(self, progress_every: int = 0,

@@ -13,17 +13,10 @@ from __future__ import annotations
 
 import numpy as np
 import jax
-from jax.sharding import Mesh
-
-try:                                    # jax >= 0.5 explicit-sharding API
-    from jax.sharding import AxisType
-except ImportError:                     # older jax: meshes are Auto-typed
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(devices: np.ndarray, axes) -> Mesh:
-    if AxisType is None:
-        return Mesh(devices, axes)
     return Mesh(devices, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

@@ -171,6 +171,8 @@ def main():
                          "with a JSON snapshot after every flushed batch "
                          "(DESIGN.md §16)")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     lines = open(args.requests) if args.requests else None
     try:
         svc = serve_discovery(lines=lines, slice_steps=args.slice_steps,

@@ -59,14 +59,13 @@ def test_sharded_bound_sync_multi_device():
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.core.engine import make_sharded_bound_sync
         from repro.core.api import NEG
-        from repro.distributed import shard_map_compat
 
         mesh = Mesh(np.array(jax.devices()).reshape(8), ("data",))
         k = 3
         sync = make_sharded_bound_sync("data", k)
-        run = jax.jit(shard_map_compat(
+        run = jax.jit(jax.shard_map(
             sync, mesh=mesh, in_specs=(P("data"), P("data")),
-            out_specs=P()))
+            out_specs=P(), check_vma=False))
 
         def pack(entries):
             # entries: {shard: [(state_tuple, key), ...]}
