@@ -42,8 +42,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import time
-from functools import partial
+from functools import partial, wraps
 from typing import Optional
 
 import numpy as np
@@ -58,6 +57,18 @@ from repro.obs import NOOP, Observability
 _CKPT_SCALARS = ("steps", "candidates", "expanded", "pruned", "refilled",
                  "syncs", "host_syncs", "threshold", "pool_occupancy",
                  "done")
+
+
+def named_program(name: str, fn):
+    """``fn`` under the name ``name``.  ``jax.jit`` names the device
+    program after the function it wraps (``jit_<name>``), and that is the
+    name the profiler's ``XLA Modules`` line and the compile log show;
+    the computation itself is unchanged."""
+    @wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 def donatable_pool_argnums():
@@ -289,10 +300,13 @@ class Engine:
         # keeps C), so T blocks can never overflow the default sizing
         self.acc_cap = max(config.overflow_accum or self.T * (self.B + self.M),
                            self.B + self.M)
-        self._step = jax.jit(self._step_impl)
-        self._insert = jax.jit(self._insert_impl)
+        self._step = jax.jit(named_program("discovery_step",
+                                           self._step_impl))
+        self._insert = jax.jit(named_program("discovery_insert",
+                                             self._insert_impl))
         if self.T > 1:
-            self._macro = jax.jit(self._macro_impl,
+            self._macro = jax.jit(named_program("discovery_macro",
+                                                self._macro_impl),
                                   donate_argnums=donatable_pool_argnums())
         # observability (DESIGN.md §16): metric handles are resolved once
         # here — the step loop touches the metric objects directly, never
@@ -320,8 +334,6 @@ class Engine:
             "engine_pool_occupancy", "live device-pool entries")
         self._g_threshold = obs.gauge(
             "engine_threshold", "current dominance threshold (k-th key)")
-        self._h_step = obs.histogram(
-            "engine_step_seconds", "wall time per engine step() call")
 
     # ------------------------------------------------------------------ step
     def _step_impl(self, pool_states, pool_prio, pool_ub,
@@ -692,19 +704,17 @@ class Engine:
         exactly the same step count for any ``steps_per_sync``.  Updates
         ``st`` in place and returns it.
         """
-        t0 = time.perf_counter() if self.obs.enabled else 0.0
         if self.T == 1:
             with self._span("engine.step"):
-                # attribution caveat (docs/OBSERVABILITY.md): jax dispatch
-                # is async, so on accelerators part of the compute lands
-                # in the host_sync span where device_get blocks
-                with self._span("engine.device_compute"):
+                # dispatch returns before the device runs; the wait is
+                # where the host blocks on the step's stats
+                with self._span("engine.dispatch"):
                     (st.pool_states, st.pool_prio, st.pool_ub,
                      st.result_states, st.result_keys, overflow,
                      stats) = self._step(
                         st.pool_states, st.pool_prio, st.pool_ub,
                         st.result_states, st.result_keys, self.comp.tables)
-                with self._span("engine.host_sync"):
+                with self._span("engine.wait"):
                     stats = jax.tree.map(int, jax.device_get(stats))
                 st.steps += 1
                 st.host_syncs += 1
@@ -712,16 +722,16 @@ class Engine:
                 st.candidates += stats["created"]
                 st.pruned += stats["pruned"]
                 st.threshold = stats["threshold"]
-                with self._span("engine.spill"):
+                with self._span("engine.fetch_overflow"):
                     st.vpq.maybe_push(*map(np.asarray, overflow))
                 self._refill(st, stats["pool_occupancy"])
-            self._after_step(st, 1, stats, t0)
+            self._after_step(st, 1, stats)
             return st
 
         t_cap = (self.T if max_inner is None
                  else max(1, min(self.T, int(max_inner))))
         with self._span("engine.step"):
-            with self._span("engine.device_compute"):
+            with self._span("engine.dispatch"):
                 (st.pool_states, st.pool_prio, st.pool_ub,
                  st.result_states, st.result_keys, acc_s, acc_p, acc_u,
                  stats) = self._macro(
@@ -729,7 +739,7 @@ class Engine:
                     st.result_states, st.result_keys, self.comp.tables,
                     np.int32(t_cap), len(st.vpq) > 0,
                     np.int32(st.pool_occupancy))
-            with self._span("engine.host_sync"):
+            with self._span("engine.wait"):
                 stats = jax.tree.map(int, jax.device_get(stats))
             st.steps += stats["steps"]
             st.host_syncs += 1
@@ -739,16 +749,16 @@ class Engine:
             st.threshold = stats["threshold"]
             w = stats["spill_count"]
             if w:  # ship only the accumulator's valid prefix; none when dry
-                with self._span("engine.spill"):
+                with self._span("engine.fetch_overflow"):
                     st.vpq.maybe_push(np.asarray(acc_s)[:w],
                                       np.asarray(acc_p)[:w],
                                       np.asarray(acc_u)[:w])
             self._refill(st, stats["pool_occupancy"])
-        self._after_step(st, stats["steps"], stats, t0)
+        self._after_step(st, stats["steps"], stats)
         return st
 
-    def _after_step(self, st: EngineState, n_steps: int, stats: dict,
-                    t0: float) -> None:
+    def _after_step(self, st: EngineState, n_steps: int, stats: dict
+                    ) -> None:
         """Record one step() call's metrics (no-op handles when off)."""
         self._m_steps.inc(n_steps)
         self._m_host_syncs.inc()
@@ -757,8 +767,6 @@ class Engine:
         self._m_pruned.inc(stats["pruned"])
         self._g_occupancy.set(st.pool_occupancy)
         self._g_threshold.set(st.threshold)
-        if self.obs.enabled:
-            self._h_step.observe(time.perf_counter() - t0)
 
     # ---------------------------------------------------------------- refill
     def _refill(self, st: EngineState, occ: int) -> None:

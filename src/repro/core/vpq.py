@@ -190,6 +190,7 @@ class VirtualPriorityQueue:
         self.run_flush_size = run_flush_size
         # observability handles, resolved once (DESIGN.md §16)
         self.obs = obs if obs is not None else NOOP
+        self._span = self.obs.tracer.span
         self._m_spilled = self.obs.counter(
             "vpq_spilled_entries_total", "entries spilled off-device")
         self._m_spill_bytes = self.obs.counter(
@@ -217,21 +218,22 @@ class VirtualPriorityQueue:
     def maybe_push(self, states: np.ndarray, prio: np.ndarray,
                    ub: np.ndarray):
         """Spill the valid (prio > NEG) entries of an overflow block."""
-        mask = prio > NEG
-        if not mask.any():
-            return
-        if self.backend == "none":
-            raise RuntimeError(
-                "priority pool overflow with spill disabled; raise "
-                "pool_capacity or enable the virtual priority queue")
-        states, prio, ub = states[mask], prio[mask], ub[mask]
-        self.total_spilled += len(prio)
-        self._m_spilled.inc(len(prio))
-        self._m_spill_bytes.inc(states.nbytes + prio.nbytes + ub.nbytes)
-        self._pending.append((states, prio, ub))
-        self._pending_n += len(prio)
-        if self._pending_n >= self.run_flush_size:
-            self._flush_pending()
+        with self._span("vpq.push"):
+            mask = prio > NEG
+            if not mask.any():
+                return
+            if self.backend == "none":
+                raise RuntimeError(
+                    "priority pool overflow with spill disabled; raise "
+                    "pool_capacity or enable the virtual priority queue")
+            states, prio, ub = states[mask], prio[mask], ub[mask]
+            self.total_spilled += len(prio)
+            self._m_spilled.inc(len(prio))
+            self._m_spill_bytes.inc(states.nbytes + prio.nbytes + ub.nbytes)
+            self._pending.append((states, prio, ub))
+            self._pending_n += len(prio)
+            if self._pending_n >= self.run_flush_size:
+                self._flush_pending()
 
     def _flush_pending(self):
         if not self._pending:
@@ -272,78 +274,80 @@ class VirtualPriorityQueue:
         Consumption stops as soon as ``n`` entries survive pruning, leaving
         later entries (dominated or not) in their runs.
         """
-        self._flush_pending()
-        out_s, out_p, out_u = [], [], []
-        need = n
-        late_pruned0 = self.total_late_pruned
-        live = [r for r in self.runs if not r.exhausted]
-        while need > 0 and live:
-            blocks = [r.buffered() for r in live]
-            prio = np.concatenate([b[1] for b in blocks]).astype(np.int64)
-            run_of = np.concatenate(
-                [np.full(len(b[1]), j, np.int64)
-                 for j, b in enumerate(blocks)])
-            order = np.argsort(-prio, kind="stable")
+        with self._span("vpq.pop"):
+            self._flush_pending()
+            out_s, out_p, out_u = [], [], []
+            need = n
+            late_pruned0 = self.total_late_pruned
+            live = [r for r in self.runs if not r.exhausted]
+            while need > 0 and live:
+                blocks = [r.buffered() for r in live]
+                prio = np.concatenate([b[1] for b in blocks]).astype(np.int64)
+                run_of = np.concatenate(
+                    [np.full(len(b[1]), j, np.int64)
+                     for j, b in enumerate(blocks)])
+                order = np.argsort(-prio, kind="stable")
 
-            bar, rmin = None, None
-            for j, r in enumerate(live):
-                if r.has_unbuffered:
-                    t = r.tail_prio
-                    if bar is None or t > bar:
-                        bar, rmin = t, j
-            if bar is None:
-                n_safe = len(order)
-            else:
-                p_sorted = prio[order]
-                safe = (p_sorted > bar) | ((p_sorted == bar)
-                                           & (run_of[order] <= rmin))
-                # monotone prefix of the merged order; never empty — the
-                # bar run's own buffered block is entirely inside it
-                n_safe = int(np.searchsorted(~safe, True))
-            take = order[:n_safe]
+                bar, rmin = None, None
+                for j, r in enumerate(live):
+                    if r.has_unbuffered:
+                        t = r.tail_prio
+                        if bar is None or t > bar:
+                            bar, rmin = t, j
+                if bar is None:
+                    n_safe = len(order)
+                else:
+                    p_sorted = prio[order]
+                    safe = (p_sorted > bar) | ((p_sorted == bar)
+                                               & (run_of[order] <= rmin))
+                    # monotone prefix of the merged order; never empty — the
+                    # bar run's own buffered block is entirely inside it
+                    n_safe = int(np.searchsorted(~safe, True))
+                take = order[:n_safe]
 
-            ub = np.concatenate([b[2] for b in blocks])
-            keep = ub[take] >= min_ub            # late dominance pruning
-            cum = np.cumsum(keep)
-            kept_total = int(cum[-1]) if n_safe else 0
-            if kept_total >= need:               # stop at the need-th keeper
-                stop = int(np.searchsorted(cum, need)) + 1
-            else:
-                stop = n_safe
-            sel = take[:stop]
-            kmask = keep[:stop]
-            kept = sel[kmask]
-            self.total_late_pruned += int(stop - kmask.sum())
+                ub = np.concatenate([b[2] for b in blocks])
+                keep = ub[take] >= min_ub            # late dominance pruning
+                cum = np.cumsum(keep)
+                kept_total = int(cum[-1]) if n_safe else 0
+                if kept_total >= need:     # stop at the need-th keeper
+                    stop = int(np.searchsorted(cum, need)) + 1
+                else:
+                    stop = n_safe
+                sel = take[:stop]
+                kmask = keep[:stop]
+                kept = sel[kmask]
+                self.total_late_pruned += int(stop - kmask.sum())
 
-            if len(kept):
-                states = np.concatenate([b[0] for b in blocks])
-                out_s.append(states[kept])
-                out_p.append(prio[kept].astype(np.int32))
-                out_u.append(ub[kept])
-                need -= len(kept)
-            for j, c in enumerate(np.bincount(run_of[sel],
-                                              minlength=len(live))):
-                if c:
-                    live[j].consume(int(c))
-            live = [r for r in live if not r.exhausted]
-        # close exhausted runs as they drop out so the disk backend's .npy
-        # run files are deleted immediately instead of leaking until close()
-        keep_runs = []
-        for r in self.runs:
-            if r.exhausted:
-                r.close()
-            else:
-                keep_runs.append(r)
-        self.runs = keep_runs
-        self._m_late_pruned.inc(self.total_late_pruned - late_pruned0)
-        if not out_p:
-            return (np.zeros((0, self.state_width), np.int32),
-                    np.zeros((0,), np.int32), np.zeros((0,), np.int32))
-        out = (np.concatenate(out_s).astype(np.int32),
-               np.concatenate(out_p),
-               np.concatenate(out_u).astype(np.int32))
-        self._m_refill_bytes.inc(sum(a.nbytes for a in out))
-        return out
+                if len(kept):
+                    states = np.concatenate([b[0] for b in blocks])
+                    out_s.append(states[kept])
+                    out_p.append(prio[kept].astype(np.int32))
+                    out_u.append(ub[kept])
+                    need -= len(kept)
+                for j, c in enumerate(np.bincount(run_of[sel],
+                                                  minlength=len(live))):
+                    if c:
+                        live[j].consume(int(c))
+                live = [r for r in live if not r.exhausted]
+            # close exhausted runs as they drop out so the disk backend's
+            # .npy run files are deleted at once instead of leaking until
+            # close()
+            keep_runs = []
+            for r in self.runs:
+                if r.exhausted:
+                    r.close()
+                else:
+                    keep_runs.append(r)
+            self.runs = keep_runs
+            self._m_late_pruned.inc(self.total_late_pruned - late_pruned0)
+            if not out_p:
+                return (np.zeros((0, self.state_width), np.int32),
+                        np.zeros((0,), np.int32), np.zeros((0,), np.int32))
+            out = (np.concatenate(out_s).astype(np.int32),
+                   np.concatenate(out_p),
+                   np.concatenate(out_u).astype(np.int32))
+            self._m_refill_bytes.inc(sum(a.nbytes for a in out))
+            return out
 
     def close(self):
         for r in self.runs:

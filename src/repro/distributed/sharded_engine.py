@@ -75,7 +75,6 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import time
 from typing import List, Optional
 
 import numpy as np
@@ -87,7 +86,8 @@ from repro.core.api import NEG, SubgraphComputation
 from repro.core.engine import (Engine, EngineConfig, EngineResult,
                                donatable_pool_argnums,
                                make_sharded_bound_sync,
-                               make_stale_bound_sync, merge_topk)
+                               make_stale_bound_sync, merge_topk,
+                               named_program)
 from repro.core.vpq import VirtualPriorityQueue
 
 
@@ -212,15 +212,17 @@ class ShardedEngine:
                     result_keys, overflow, stats)
 
         self._step_sharded = jax.jit(jax.shard_map(
-            body, mesh=self.mesh, in_specs=(spec,) * 5 + (P(),),
+            named_program("discovery_step_sharded", body), mesh=self.mesh,
+            in_specs=(spec,) * 5 + (P(),),
             out_specs=((spec,) * 5 + ((spec, spec, spec),
                                       {name: spec for name in _STAT_KEYS})),
             check_vma=False))
         # refill / rebalance blocks enter through the same merge-sort insert
         # as overflow handling, one fixed [shards*C] block per call
         self._insert_sharded = jax.jit(jax.shard_map(
-            self._eng._insert_impl, mesh=self.mesh, in_specs=(spec,) * 6,
-            out_specs=(spec,) * 6, check_vma=False))
+            named_program("discovery_insert_sharded", self._eng._insert_impl),
+            mesh=self.mesh, in_specs=(spec,) * 6, out_specs=(spec,) * 6,
+            check_vma=False))
 
         # fused macro-step (DESIGN.md §13/§14): the per-shard while_loop
         # with the §4 threshold collective at segment heads (every step at
@@ -257,7 +259,8 @@ class ShardedEngine:
                 return ps, pp, pu, rs, rk, acc_s, acc_p, acc_u, stats
 
             self._macro_sharded = jax.jit(jax.shard_map(
-                macro_body, mesh=self.mesh,
+                named_program("discovery_macro_sharded", macro_body),
+                mesh=self.mesh,
                 in_specs=(spec,) * 5 + (P(), P(), spec, spec),
                 out_specs=((spec,) * 8 +
                            ({name: spec for name in stat_keys},)),
@@ -322,19 +325,16 @@ class ShardedEngine:
         the same count for any ``steps_per_sync``.
         """
         shards, cap = self.shards, self._eng.acc_cap
-        t0 = time.perf_counter() if self.obs.enabled else 0.0
         if self.T == 1:
             with self._span("engine.step"):
-                with self._span("engine.device_compute"):
+                with self._span("engine.dispatch"):
                     (st.pool_states, st.pool_prio, st.pool_ub,
                      st.result_states, st.result_keys, overflow,
                      stats) = self._step_sharded(
                         st.pool_states, st.pool_prio, st.pool_ub,
                         st.result_states, st.result_keys, self._tables)
-                with self._span("engine.host_sync"):
+                with self._span("engine.wait"):
                     stats = jax.device_get(stats)  # each value: [shards]
-                    o_s, o_p, o_u = (np.asarray(a) for a in overflow)
-                o_per = len(o_p) // shards
 
                 st.steps += 1
                 st.syncs += 1          # one §4 exchange per unfused step
@@ -345,18 +345,20 @@ class ShardedEngine:
                 st.threshold = int(stats["threshold"][0])  # replicated, §4
                 occ = stats["pool_occupancy"].astype(np.int64)
 
-                with self._span("engine.spill"):
+                with self._span("engine.fetch_overflow"):
+                    o_s, o_p, o_u = (np.asarray(a) for a in overflow)
+                    o_per = len(o_p) // shards
                     for i in range(shards):
                         sl = slice(i * o_per, (i + 1) * o_per)
                         st.vpqs[i].maybe_push(o_s[sl], o_p[sl], o_u[sl])
                 st = self._refill_rebalance(st, occ)
-            self._after_step(st, 1, 1, stats, t0)
+            self._after_step(st, 1, 1, stats)
             return st
 
         t_cap = (self.T if max_inner is None
                  else max(1, min(self.T, int(max_inner))))
         with self._span("engine.step"):
-            with self._span("engine.device_compute"):
+            with self._span("engine.dispatch"):
                 (st.pool_states, st.pool_prio, st.pool_ub,
                  st.result_states, st.result_keys, acc_s, acc_p, acc_u,
                  stats) = self._macro_sharded(
@@ -365,7 +367,7 @@ class ShardedEngine:
                     np.int32(t_cap),
                     np.asarray([len(v) > 0 for v in st.vpqs]),
                     st.pool_occupancy.astype(np.int32))
-            with self._span("engine.host_sync"):
+            with self._span("engine.wait"):
                 stats = jax.device_get(stats)     # each value: [shards]
             n = int(stats["steps"][0])            # uniform: global exit vote
             st.steps += n
@@ -386,9 +388,9 @@ class ShardedEngine:
             occ = stats["pool_occupancy"].astype(np.int64)
             spill = stats["spill_count"]
             if spill.any():   # ship each shard's valid accumulator prefix
-                acc_s, acc_p, acc_u = (np.asarray(a)
-                                       for a in (acc_s, acc_p, acc_u))
-                with self._span("engine.spill"):
+                with self._span("engine.fetch_overflow"):
+                    acc_s, acc_p, acc_u = (np.asarray(a)
+                                           for a in (acc_s, acc_p, acc_u))
                     for i in range(shards):
                         w = int(spill[i])
                         if w:
@@ -397,11 +399,11 @@ class ShardedEngine:
                                                   acc_p[base:base + w],
                                                   acc_u[base:base + w])
             st = self._refill_rebalance(st, occ)
-        self._after_step(st, n, -(-n // self.K), stats, t0)
+        self._after_step(st, n, -(-n // self.K), stats)
         return st
 
     def _after_step(self, st: ShardedEngineState, n_steps: int,
-                    n_syncs: int, stats: dict, t0: float) -> None:
+                    n_syncs: int, stats: dict) -> None:
         """Record one step() call's metrics (no-op handles when off)."""
         eng = self._eng
         eng._m_steps.inc(n_steps)
@@ -412,8 +414,6 @@ class ShardedEngine:
         eng._m_pruned.inc(int(stats["pruned"].sum()))
         eng._g_occupancy.set(int(st.pool_occupancy.sum()))
         eng._g_threshold.set(st.threshold)
-        if self.obs.enabled:
-            eng._h_step.observe(time.perf_counter() - t0)
 
     # ----------------------------------------------------- refill/rebalance
     def _refill_rebalance(self, st: ShardedEngineState,

@@ -100,6 +100,7 @@ def _masked_intersect(a_bits, b_bits, mask_bits,
         out_specs=pl.BlockSpec((bb, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bp, np_), jnp.int32),
         interpret=interpret,
+        name="masked_intersect",
     )(*operands)
     return out[:b, :n]
 

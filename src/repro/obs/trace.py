@@ -1,13 +1,17 @@
 """Span tracer with an in-memory ring buffer (DESIGN.md §16).
 
-A *span* is a named timed phase (``engine.step``, ``vpq.refill``,
+A *span* is a named timed phase (``engine.step``, ``vpq.pop``,
 ``checkpoint.commit`` ...).  :meth:`SpanTracer.span` returns a context
-manager; on exit the completed span is recorded as a plain tuple
-``(name, start_s, dur_s, tid)`` into a fixed-capacity ring buffer —
-recording is an index increment plus a tuple store under a lock, no
-allocation beyond the tuple, so tracing the per-step hot path stays
-inside the §16 overhead budget.  When the ring wraps, the oldest spans
-are dropped and :attr:`SpanTracer.dropped` counts them.
+manager that does two things:
+
+* it opens a ``jax.profiler.TraceAnnotation`` of the same name (with the
+  span's keyword metadata) for its lifetime, so under a profiler trace the
+  program's spans sit on the host planes next to the runtime's own events
+  and on the same clock as the device's operations;
+* on exit it records the completed span as a plain tuple ``(name,
+  start_s, dur_s, tid)`` into a fixed-capacity ring buffer — an index
+  increment plus a tuple store under a lock.  When the ring wraps, the
+  oldest spans are dropped and :attr:`SpanTracer.dropped` counts them.
 
 The buffer exports the Chrome trace-event JSON format (``ph: "X"``
 complete events with microsecond ``ts``/``dur``), which loads directly
@@ -26,25 +30,31 @@ import threading
 import time
 from typing import List, Optional
 
+from jax.profiler import TraceAnnotation
+
 
 class _Span:
-    """Context manager recording one completed span on ``__exit__``.
-    Spans are recorded even when the body raises — a phase that died
-    mid-flight is exactly what a trace should show."""
+    """Context manager holding a profiler annotation open and recording
+    one completed span on ``__exit__``.  Spans are recorded even when the
+    body raises — a phase that died mid-flight is exactly what a trace
+    should show."""
 
-    __slots__ = ("_tracer", "name", "_t0")
+    __slots__ = ("_tracer", "name", "_ann", "_t0")
 
-    def __init__(self, tracer: "SpanTracer", name: str):
+    def __init__(self, tracer: "SpanTracer", name: str, meta: dict):
         self._tracer = tracer
         self.name = name
+        self._ann = TraceAnnotation(name, **meta)
         self._t0 = 0.0
 
     def __enter__(self) -> "_Span":
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         t1 = time.perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
         self._tracer._record(self.name, self._t0, t1 - self._t0)
 
 
@@ -62,8 +72,10 @@ class SpanTracer:
         self._epoch_wall = time.time()
         self._epoch_perf = time.perf_counter()
 
-    def span(self, name: str) -> _Span:
-        return _Span(self, name)
+    def span(self, name: str, **meta) -> _Span:
+        """A span named ``name``; ``meta`` (e.g. ``request_id=``) rides
+        on its profiler annotation only."""
+        return _Span(self, name, meta)
 
     def _record(self, name: str, start: float, dur: float) -> None:
         tid = threading.get_ident()

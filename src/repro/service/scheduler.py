@@ -17,13 +17,14 @@ from __future__ import annotations
 
 import copy
 import time
+import weakref
 from typing import Dict, List, Optional
 
 
 from repro.core.aggregate import TopKPatternMiner
 from repro.core.engine import NEG, Engine
 from repro.core.graph import GraphStore
-from repro.obs import NOOP
+from repro.obs import NOOP, watch_jax_compiles
 from repro.runtime.fault_tolerance import StragglerMonitor
 
 from .api import (DiscoveryRequest, DiscoveryResponse, GraphRegistry,
@@ -42,7 +43,7 @@ class EngineQueryTask:
     """
 
     def __init__(self, request: DiscoveryRequest, engine: Engine,
-                 obs=NOOP):
+                 obs=NOOP, task_id: int = 0):
         self.request = request
         self.comp = engine.comp
         self.engine = engine
@@ -50,6 +51,15 @@ class EngineQueryTask:
         # this task's first scheduled step under the round-robin
         self._obs = obs
         self._admitted = time.perf_counter() if obs.enabled else 0.0
+        # what each step's span carries (nothing when tracing is off): the
+        # service's admission number and, where the client set one, the
+        # request's own id
+        self._span = obs.tracer.span
+        self._span_meta = {}
+        if obs.enabled:
+            self._span_meta["task"] = task_id
+            if request.request_id is not None:
+                self._span_meta["request_id"] = request.request_id
         self._started = False
         # durable runs (DESIGN.md §15): resume re-admits the query from the
         # newest committed checkpoint; checkpoint_every persists it as it
@@ -94,34 +104,37 @@ class EngineQueryTask:
     def step(self) -> None:
         if self.finished:
             return
-        # one scheduled step is one engine macro-step (steps_per_sync fused
-        # super-steps); capping the fused count to the remaining budget
-        # keeps step_budget truncation exact for any steps_per_sync
-        t0 = time.perf_counter()
-        if not self._started:
-            self._started = True
-            if self._obs.enabled:
-                self._obs.histogram(
-                    "service_queue_wait_seconds",
-                    "admission-to-first-step wait under the scheduler"
-                ).observe(t0 - self._admitted)
-        self.engine.step(self.state,
-                         max_inner=self.request.step_budget
-                         - self.state.steps)
-        self.straggler.record(self.state.steps, time.perf_counter() - t0)
-        # budgets come from the request, not engine.cfg: the engine may be
-        # shared with requests that differ only in budgets
-        if self.state.done:
-            self.terminated = "complete"
-        elif self.state.steps >= self.request.step_budget:
-            self.terminated = "step_budget"
-        elif self._over_candidate_budget():
-            self.terminated = "candidate_budget"
-        if self._mgr is not None and self.request.checkpoint_every > 0 and \
-                self.state.steps - self._last_ckpt >= \
-                self.request.checkpoint_every:
-            self.engine.save_checkpoint(self._mgr, self.state)
-            self._last_ckpt = self.state.steps
+        with self._span("service.task_step", **self._span_meta):
+            # one scheduled step is one engine macro-step (steps_per_sync
+            # fused super-steps); capping the fused count to the remaining
+            # budget keeps step_budget truncation exact for any
+            # steps_per_sync
+            t0 = time.perf_counter()
+            if not self._started:
+                self._started = True
+                if self._obs.enabled:
+                    self._obs.histogram(
+                        "service_queue_wait_seconds",
+                        "admission-to-first-step wait under the scheduler"
+                    ).observe(t0 - self._admitted)
+            self.engine.step(self.state,
+                             max_inner=self.request.step_budget
+                             - self.state.steps)
+            self.straggler.record(self.state.steps, time.perf_counter() - t0)
+            # budgets come from the request, not engine.cfg: the engine may
+            # be shared with requests that differ only in budgets
+            if self.state.done:
+                self.terminated = "complete"
+            elif self.state.steps >= self.request.step_budget:
+                self.terminated = "step_budget"
+            elif self._over_candidate_budget():
+                self.terminated = "candidate_budget"
+            if self._mgr is not None and \
+                    self.request.checkpoint_every > 0 and \
+                    self.state.steps - self._last_ckpt >= \
+                    self.request.checkpoint_every:
+                self.engine.save_checkpoint(self._mgr, self.state)
+                self._last_ckpt = self.state.steps
 
     def finalize(self) -> dict:
         if self._payload is not None:
@@ -289,6 +302,7 @@ class DiscoveryService:
         # a batch.  LRU-bounded; TTL is irrelevant for compiled code.
         self._engines = ResultCache(capacity=engine_cache_size,
                                     ttl_s=float("inf"))
+        self._tasks_admitted = 0
         self.engine_steps_total = 0
         self.requests_served = 0
         # observability (DESIGN.md §16): one shared registry for service
@@ -307,8 +321,24 @@ class DiscoveryService:
         self._m_engine_steps = self.obs.counter(
             "service_engine_steps_total",
             "engine super-steps run on behalf of this service")
+        self._m_engine_builds = self.obs.counter(
+            "service_engine_builds_total",
+            "engines built on an engine-cache miss")
         self._h_request = self.obs.histogram(
             "service_request_seconds", "per-request wall time")
+        # the service owns the engine builds, so an observed one counts
+        # the process's JAX compile seconds too; a dropped service stops
+        # counting when it is collected
+        self._unwatch = None
+        if self.obs.enabled:
+            self._unwatch = weakref.finalize(
+                self, watch_jax_compiles(self.obs.metrics))
+            self._unwatch.atexit = False
+
+    def close(self) -> None:
+        """Stop counting JAX's compile events (idempotent)."""
+        if self._unwatch is not None:
+            self._unwatch()
 
     def register_graph(self, name: str, graph) -> None:
         self.registry.register(name, graph)
@@ -359,23 +389,24 @@ class DiscoveryService:
         with self.obs.span("service.drive"):
             self.scheduler.drive([task for _, _, task in pending])
 
-        for indices, key, task in pending:
-            payload = task.finalize()
-            if isinstance(task, EngineQueryTask):
-                # count only the steps this admission actually ran: a
-                # resumed state arrives carrying its pre-crash step count
-                ran = task.state.steps - task.steps_at_admission
-                self.engine_steps_total += ran
-                self._m_engine_steps.inc(ran)
-            if key is not None:
-                self.cache.put(key, payload)
-            for j, i in enumerate(indices):
-                if j > 0:   # within-batch dedup joins are cache hits too
-                    self._m_cache_hits.inc()
-                lat = time.perf_counter() - t0
-                self._h_request.observe(lat)
-                responses[i] = self._payload_to_response(
-                    requests[i], payload, cached=j > 0, latency_s=lat)
+        with self.obs.span("service.finalize"):
+            for indices, key, task in pending:
+                payload = task.finalize()
+                if isinstance(task, EngineQueryTask):
+                    # count only the steps this admission actually ran: a
+                    # resumed state arrives carrying its pre-crash steps
+                    ran = task.state.steps - task.steps_at_admission
+                    self.engine_steps_total += ran
+                    self._m_engine_steps.inc(ran)
+                if key is not None:
+                    self.cache.put(key, payload)
+                for j, i in enumerate(indices):
+                    if j > 0:   # within-batch dedup joins are cache hits
+                        self._m_cache_hits.inc()
+                    lat = time.perf_counter() - t0
+                    self._h_request.observe(lat)
+                    responses[i] = self._payload_to_response(
+                        requests[i], payload, cached=j > 0, latency_s=lat)
 
         self.requests_served += len(requests)
         return responses   # type: ignore[return-value]
@@ -410,18 +441,24 @@ class DiscoveryService:
         engine_key = make_cache_key(graph.fingerprint, engine_spec)
         engine = self._engines.get(engine_key)
         if engine is None:
-            compiled = compile_request(req, self.registry, graph=graph)
-            if req.observe and self.obs.enabled:
-                # observing engines record into the service registry so a
-                # single snapshot covers the whole process (DESIGN.md §16)
-                compiled.engine_cfg.observability = self.obs
-            if compiled.engine_cfg.shards > 1:
-                from repro.distributed import ShardedEngine
-                engine = ShardedEngine(compiled.comp, compiled.engine_cfg)
-            else:
-                engine = Engine(compiled.comp, compiled.engine_cfg)
+            with self.obs.span("service.build_engine"):
+                engine = self._build_engine(req, graph)
+            self._m_engine_builds.inc()
             self._engines.put(engine_key, engine)
-        return EngineQueryTask(req, engine, obs=self.obs)
+        self._tasks_admitted += 1
+        return EngineQueryTask(req, engine, obs=self.obs,
+                               task_id=self._tasks_admitted)
+
+    def _build_engine(self, req: DiscoveryRequest, graph: GraphStore):
+        compiled = compile_request(req, self.registry, graph=graph)
+        if req.observe and self.obs.enabled:
+            # observing engines record into the service registry so a
+            # single snapshot covers the whole process (DESIGN.md §16)
+            compiled.engine_cfg.observability = self.obs
+        if compiled.engine_cfg.shards > 1:
+            from repro.distributed import ShardedEngine
+            return ShardedEngine(compiled.comp, compiled.engine_cfg)
+        return Engine(compiled.comp, compiled.engine_cfg)
 
     @staticmethod
     def _payload_to_response(req: DiscoveryRequest, payload: dict,

@@ -1,22 +1,33 @@
 """Observability subsystem (DESIGN.md §16): metrics registry semantics,
 span-tracer ring buffer + Chrome trace export, no-op identities, engine
-instrumentation parity (observe on == observe off, byte-for-byte), and
-service-layer metrics with the pure-observer cache-key discipline."""
+instrumentation parity (observe on == observe off, byte-for-byte),
+service-layer metrics with the pure-observer cache-key discipline, spans
+in a profiler trace, JAX compile counters, and the device programs'
+names."""
 import dataclasses
+import gc
+import glob
 import json
+import os
+import re
+import subprocess
+import sys
+import textwrap
+import time
+import warnings
 
 import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro.core.clique import make_clique_computation
 from repro.core.engine import Engine, EngineConfig
 from repro.data.synthetic_graphs import densifying_graph
 from repro.obs import (NOOP, NULL_METRIC, NULL_REGISTRY, NULL_SPAN,
                        NULL_TRACER, MetricsRegistry, Observability,
-                       SpanTracer, TOP_LEVEL_SPANS, aggregate, coverage,
-                       format_table, log_buckets)
+                       SpanTracer, log_buckets)
 from repro.obs.metrics import DEFAULT_TIME_BUCKETS
 from repro.service import DiscoveryRequest, DiscoveryService
 
@@ -215,22 +226,6 @@ def test_snapshot_shapes():
     assert noop_snap["enabled"] is False and noop_snap["metrics"] == {}
 
 
-# -------------------------------------------------------------------- report
-def test_aggregate_and_format_table():
-    spans = [("engine.step", 0.0, 0.2, 1), ("engine.step", 0.2, 0.4, 1),
-             ("engine.refill", 0.3, 0.1, 1)]
-    agg = aggregate(spans)
-    assert list(agg) == ["engine.step", "engine.refill"]   # total desc
-    assert agg["engine.step"] == {"count": 2, "total_s": pytest.approx(0.6),
-                                  "max_s": pytest.approx(0.4)}
-    table = format_table(spans, wall_s=1.0)
-    assert "engine.step" in table and "% wall" in table
-    assert "coverage" in table
-    # nested spans excluded from coverage: only engine.step counts here
-    assert coverage(spans, 1.0) == pytest.approx(0.6)
-    assert coverage(spans, 0.0) == 0.0
-
-
 # ----------------------------------------------- engine instrumentation
 @pytest.fixture(scope="module")
 def clique_setup():
@@ -277,8 +272,8 @@ def test_observe_parity(clique_setup, shards, T):
     assert m.get("vpq_spilled_entries_total").value == res.spilled
     assert eng.obs.tracer.total_recorded > 0
     names = {s[0] for s in eng.obs.tracer.spans()}
-    assert {"engine.start", "engine.step", "engine.device_compute",
-            "engine.host_sync", "engine.finalize"} <= names
+    assert {"engine.start", "engine.step", "engine.dispatch",
+            "engine.wait", "engine.finalize"} <= names
 
 
 def test_observe_off_records_nothing(clique_setup):
@@ -288,22 +283,6 @@ def test_observe_off_records_nothing(clique_setup):
     _assert_parity(ref, res)
     assert eng.obs is NOOP
     assert eng.obs.tracer.total_recorded == 0
-
-
-def test_observe_coverage(clique_setup):
-    """Top-level spans account for nearly all of an instrumented run's
-    wall time (the §16 ≥90% acceptance bar is asserted on the larger
-    bench cell; this is the fast smoke floor)."""
-    import time
-    comp, cfg, _ref = clique_setup
-    eng = Engine(comp, dataclasses.replace(cfg, observe=True))
-    t0 = time.perf_counter()
-    eng.run()
-    wall = time.perf_counter() - t0
-    spans = eng.obs.tracer.spans()
-    cov = coverage(spans, wall)
-    assert cov >= 0.85, format_table(spans, wall)
-    assert cov <= 1.5   # sanity: not double-counting nested spans
 
 
 def test_shared_observability_across_engines(clique_setup):
@@ -392,3 +371,314 @@ def test_service_default_is_noop(social):
                                       k=3, step_budget=40))
     assert resp.status == "ok"
     assert NOOP.tracer.total_recorded == 0
+
+
+# ------------------------------------------------- spans in a profiler trace
+def _host_events(trace_dir):
+    """Host events of the profiler trace under ``trace_dir``:
+    ``(line, name, start_ns, end_ns, stats)`` rows."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                stats = {}
+                if e.name == "service.task_step":
+                    with warnings.catch_warnings():   # event_stats' type
+                        warnings.simplefilter("ignore", DeprecationWarning)
+                        stats = dict(e.stats)
+                out.append((line.name, e.name, e.start_ns,
+                            e.start_ns + e.duration_ns, stats))
+    return out
+
+
+@pytest.fixture(scope="module")
+def served_trace(tmp_path_factory):
+    """A profiler trace of one observed service batch of two clique
+    queries whose pools spill and refill, and the batch's answers."""
+    g = densifying_graph(96, 900, seed=0)
+    svc = DiscoveryService(observability=Observability())
+    svc.register_graph("g", g)
+    reqs = [DiscoveryRequest(graph="g", workload="clique", k=k, batch=8,
+                             pool_capacity=128, observe=True,
+                             request_id=f"q{k}") for k in (3, 4)]
+    trace_dir = str(tmp_path_factory.mktemp("trace"))
+    jax.profiler.start_trace(trace_dir)
+    resps = svc.serve(reqs)
+    jax.profiler.stop_trace()
+    assert all(r.status == "ok" for r in resps)
+    assert all(r.stats["spilled"] > 0 and r.stats["refilled"] > 0
+               for r in resps)
+    return _host_events(trace_dir), resps, reqs
+
+
+def _inside(child, parent):
+    return (child[0] == parent[0] and parent[2] <= child[2]
+            and child[3] <= parent[3])
+
+
+@pytest.mark.parametrize("child,parent", [
+    ("service.task_step", "service.drive"),
+    ("engine.step", "service.task_step"),
+    ("engine.dispatch", "engine.step"),
+    ("engine.wait", "engine.step"),
+    ("engine.fetch_overflow", "engine.step"),
+    ("engine.refill", "engine.step"),
+    ("vpq.pop", "engine.refill"),
+    ("engine.finalize", "service.finalize"),
+])
+def test_spans_nest_in_the_profiler_trace(served_trace, child, parent):
+    events, _resps, _reqs = served_trace
+    children = [e for e in events if e[1] == child]
+    parents = [e for e in events if e[1] == parent]
+    assert children and parents
+    assert all(any(_inside(c, p) for p in parents) for c in children)
+
+
+def test_vpq_push_times_the_push_inside_the_fetch(served_trace):
+    events, _resps, _reqs = served_trace
+    fetches = [e for e in events if e[1] == "engine.fetch_overflow"]
+    pushes = [e for e in events if e[1] == "vpq.push"]
+    assert any(_inside(p, f) for p in pushes for f in fetches)
+
+
+def test_engine_built_and_started_outside_the_drive(served_trace):
+    events, _resps, _reqs = served_trace
+    drives = [e for e in events if e[1] == "service.drive"]
+    for name in ("service.build_engine", "engine.start"):
+        spans = [e for e in events if e[1] == name]
+        assert len(spans) == 2                     # one per query
+        assert not any(_inside(s, d) for s in spans for d in drives)
+
+
+def test_task_step_carries_the_request_id(served_trace):
+    events, resps, reqs = served_trace
+    steps = [e for e in events if e[1] == "service.task_step"]
+    ids = {e[4].get("request_id") for e in steps}
+    assert ids == {r.request_id for r in reqs}
+    # one task number per query, shared by all of its steps
+    by_id = {}
+    for e in steps:
+        by_id.setdefault(e[4]["request_id"], set()).add(e[4]["task"])
+    assert all(len(t) == 1 for t in by_id.values())
+    assert sum(r.stats["host_syncs"] for r in resps) == len(steps)
+
+
+def test_observed_service_answers_as_unobserved(served_trace):
+    g = densifying_graph(96, 900, seed=0)
+    _events, resps, reqs = served_trace
+    svc = DiscoveryService()
+    svc.register_graph("g", g)
+    plain = svc.serve([dataclasses.replace(r, observe=False) for r in reqs])
+    for a, b in zip(resps, plain):
+        assert json.dumps(a.results) == json.dumps(b.results)
+        assert a.result_keys == b.result_keys
+        # every count but the wall-clock straggler flag
+        a.stats.pop("straggler_steps")
+        b.stats.pop("straggler_steps")
+        assert a.stats == b.stats
+
+
+def test_span_without_profiler_records_ring_only():
+    t = SpanTracer(capacity=4)
+    with t.span("service.task_step", request_id="r1", task=7):
+        pass
+    assert [s[0] for s in t.spans()] == ["service.task_step"]
+    assert NOOP.span("x", request_id="r") is NULL_SPAN
+
+
+# ------------------------------------------------------ JAX compile counters
+JAX_SECONDS = ("jax_trace_seconds_total", "jax_lower_seconds_total",
+               "jax_backend_compile_seconds_total")
+
+
+def _jax_seconds(obs):
+    return {n: obs.metrics.get(n).value for n in JAX_SECONDS}
+
+
+def _fresh_jit():
+    """Compile and run a program no other test has: a new lambda is a new
+    cache entry for ``jax.jit``."""
+    jax.jit(lambda x: x * 3 + 1)(np.arange(7, dtype=np.int32)
+                                 ).block_until_ready()
+
+
+def test_jax_counters_count_a_fresh_jit_only():
+    svc = DiscoveryService(observability=Observability())
+    try:
+        assert set(_jax_seconds(svc.obs).values()) == {0}
+        f = jax.jit(lambda x: x * 3 + 1)
+        x = np.arange(7, dtype=np.int32)
+        f(x).block_until_ready()
+        first = _jax_seconds(svc.obs)
+        assert all(v > 0 for v in first.values())
+        f(x).block_until_ready()                  # cached: no new work
+        assert _jax_seconds(svc.obs) == first
+    finally:
+        svc.close()
+
+
+def test_nested_traces_count_their_seconds_once():
+    """A jit traced inside its caller's trace reports a trace of its own,
+    but its seconds lie inside the caller's and are not added again."""
+    def nest(depth):
+        if depth == 0:
+            return lambda x: jnp.sin(x) * 2
+        inner = jax.jit(nest(depth - 1))
+        return lambda x: inner(x) + inner(x + 1)
+
+    svc = DiscoveryService(observability=Observability())
+    try:
+        f = jax.jit(nest(8))
+        t0 = time.perf_counter()
+        f.trace(np.ones(4, np.float32))
+        wall = time.perf_counter() - t0
+        assert 0 < _jax_seconds(svc.obs)["jax_trace_seconds_total"] <= wall
+    finally:
+        svc.close()
+
+
+def test_close_stops_counting():
+    svc = DiscoveryService(observability=Observability())
+    svc.close()
+    svc.close()                                   # idempotent
+    _fresh_jit()
+    assert set(_jax_seconds(svc.obs).values()) == {0}
+
+
+def test_dropped_service_stops_counting():
+    obs = Observability()
+    svc = DiscoveryService(observability=obs)
+    del svc
+    gc.collect()
+    _fresh_jit()
+    assert set(_jax_seconds(obs).values()) == {0}
+
+
+@pytest.mark.parametrize("owner", ["noop_service", "observed_engine"])
+def test_only_an_observed_service_counts_compiles(clique_setup, owner):
+    """An unobserved service counts nothing, and an observed engine's own
+    registry holds spans and metrics only: the listeners are the
+    process-owning service's."""
+    if owner == "noop_service":
+        obs = DiscoveryService().obs
+        assert obs is NOOP
+    else:
+        comp, cfg, _ref = clique_setup
+        obs = Engine(comp, dataclasses.replace(cfg, observe=True)).obs
+        assert obs.enabled
+    _fresh_jit()
+    assert all(obs.metrics.get(n) is None for n in JAX_SECONDS)
+
+
+# ------------------------------------------------------------- engine builds
+def test_engine_builds_count_cache_misses(social):
+    svc = _service(social, observability=Observability())
+    builds = svc.obs.metrics.get("service_engine_builds_total")
+    rng = np.random.default_rng(0)
+
+    def req(weights, **kw):
+        return DiscoveryRequest(graph="social", workload="weighted-clique",
+                                k=3, weights=weights, observe=True, **kw)
+
+    w1, w2 = (tuple(int(x) for x in rng.integers(1, 100, social.n))
+              for _ in range(2))
+    svc.serve([req(w1), req(w2)])
+    assert builds.value == 2                      # one per weighting
+    svc.serve([req(w1, use_cache=False)])         # engine reused
+    assert builds.value == 2
+    svc.serve([req(w1)])                          # result-cache hit
+    assert builds.value == 2
+
+
+# ----------------------------------------------------- device program names
+def _normalized(hlo_text):
+    """Lowered text without module name, op-name metadata and locations."""
+    text = re.sub(r"module @\S+", "module", hlo_text)
+    text = re.sub(r"jit\([\w.]+\)", "jit()", text)
+    return re.sub(r"\s*loc\(.*", "", text)
+
+
+def _module_name(lowered):
+    return re.search(r"module @(\S+)", lowered.as_text()).group(1)
+
+
+def test_engine_programs_are_named(clique_setup):
+    comp, cfg, _ref = clique_setup
+    eng1 = Engine(comp, cfg)
+    eng4 = Engine(comp, dataclasses.replace(cfg, steps_per_sync=4))
+    st = eng1.start()
+    pool = (st.pool_states, st.pool_prio, st.pool_ub)
+    args = pool + (st.result_states, st.result_keys, comp.tables)
+    new = tuple(a[:4] for a in pool)
+    lowered = {
+        "jit_discovery_step": (eng1._step.lower(*args),
+                               jax.jit(eng1._step_impl).lower(*args)),
+        "jit_discovery_insert": (eng1._insert.lower(*pool, *new),
+                                 jax.jit(eng1._insert_impl).lower(*pool,
+                                                                  *new)),
+        "jit_discovery_macro": (
+            eng4._macro.lower(*args, np.int32(4), False, np.int32(3)),
+            jax.jit(eng4._macro_impl).lower(*args, np.int32(4), False,
+                                            np.int32(3))),
+    }
+    for name, (named, plain) in lowered.items():
+        assert _module_name(named) == name
+        # the same computation as the unnamed jit of the same function
+        assert _normalized(named.as_text()) == _normalized(plain.as_text())
+
+
+def test_sharded_programs_are_named_on_four_devices():
+    prog = """
+        import dataclasses, re
+        import numpy as np
+        import jax
+        from repro.core.clique import make_clique_computation
+        from repro.core.engine import EngineConfig
+        from repro.data.synthetic_graphs import densifying_graph
+        from repro.distributed import ShardedEngine
+        assert len(jax.devices()) == 4
+        comp = make_clique_computation(densifying_graph(64, 400, seed=0))
+        cfg = EngineConfig(k=3, batch=8, pool_capacity=64, shards=4)
+        names = []
+        for kw in (dict(), dict(steps_per_sync=4)):
+            eng = ShardedEngine(comp, dataclasses.replace(cfg, **kw))
+            st = eng.start()
+            args = (st.pool_states, st.pool_prio, st.pool_ub,
+                    st.result_states, st.result_keys, eng._tables)
+            if eng.T == 1:
+                lowered = [eng._step_sharded.lower(*args),
+                           eng._insert_sharded.lower(*args[:3], *args[:3])]
+            else:
+                lowered = [eng._macro_sharded.lower(
+                    *args, np.int32(4), np.zeros(4, bool),
+                    st.pool_occupancy.astype(np.int32))]
+            names += [re.search(r"module @(\\S+)", l.as_text()).group(1)
+                      for l in lowered]
+        print(" ".join(names))
+    """
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(os.path.dirname(os.path.dirname(
+                       os.path.abspath(__file__))), "src"),
+                    os.environ.get("PYTHONPATH", "")]))
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(prog)],
+                         env=env, capture_output=True, text=True,
+                         timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert out.stdout.split() == ["jit_discovery_step_sharded",
+                                  "jit_discovery_insert_sharded",
+                                  "jit_discovery_macro_sharded"]
+
+
+def test_masked_intersect_kernel_is_named():
+    from repro.kernels.masked_intersect import masked_intersect
+    a = np.ones((8, 4), np.uint32)
+    text = jax.jit(lambda a, b: masked_intersect(a, b, interpret=True)
+                   ).lower(a, a).as_text()
+    assert "masked_intersect" in text
