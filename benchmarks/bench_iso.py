@@ -109,7 +109,7 @@ def run_candidate_paths(n=150, m=500, n_labels=3, seed=0, batch=64,
     rows, keys = [], {}
     for path, kw in CAND_PATHS:
         comp = make_iso_computation(g, q_edges, q_labels, index, **kw)
-        states, _, _ = comp.init_frontier()
+        states, _, _ = comp.init_frontier(comp.tables)
         reps = -(-batch // states.shape[0])          # tile seeds up to batch
         block = jnp.concatenate([states] * reps)[:batch]
         step = jax.jit(comp.score_children)

@@ -29,8 +29,10 @@ States are fixed-width ``int32`` vectors; actions are integers in
 ``tables`` (a pytree of graph-sized device arrays, e.g. clique's ``[N, W]``
 extension masks) as their last argument: the engine passes them into its
 jitted programs as arguments, so they are never compiled into the
-executable as constants.  ``score_children`` performs *targeted expansion*: it
-returns ``NEG`` priority for any (state, action) that must not be created,
+executable as constants, and one compiled engine serves any tables of the
+same tree, shapes and dtypes (weighted clique's per-query weights).
+``score_children`` performs *targeted expansion*: it returns ``NEG``
+priority for any (state, action) that must not be created,
 so irrelevant subgraphs are never materialized (contrast: Arabesque's
 exhaustive expansion + post-filter, implemented in
 :mod:`repro.core.exhaustive` as the baseline).
@@ -54,8 +56,10 @@ class SubgraphComputation:
     state_width: int   # S: int32 words per subgraph state
     num_actions: int   # A: action space (e.g. N vertices)
 
-    # () -> (states [n0, S], prio [n0], ub [n0])
-    init_frontier: Callable[[], Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]]
+    # (tables) -> (states [n0, S], prio [n0], ub [n0]); traced once per
+    # engine (jitted), so n0 is fixed by the computation
+    init_frontier: Callable[[Any],
+                            Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]]
 
     # (states [B, S], tables) -> (child_prio [B, A], child_ub [B, A]);
     # NEG = not expandable
@@ -75,7 +79,9 @@ class SubgraphComputation:
     describe: Optional[Callable] = None
 
     # pytree of device arrays the callbacks above read (the last argument
-    # of each); the engine passes it into its jitted programs
+    # of each); the engine passes it into its jitted programs.  These are
+    # the defaults: a query may run on other tables of the same tree,
+    # shapes and dtypes (Engine.start)
     tables: Any = ()
 
     def __post_init__(self):
@@ -92,7 +98,7 @@ class SubgraphComputation:
 def from_pointwise(name: str,
                    state_width: int,
                    num_actions: int,
-                   init_frontier,
+                   init_frontier,    # (tables) -> (states, prio, ub)
                    expandable,       # (state [S], action, tables) -> bool
                    child_priority,   # (state [S], action, tables) -> int32
                    child_ub,         # (state [S], action, tables) -> int32

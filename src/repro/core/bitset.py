@@ -122,6 +122,13 @@ def eye_table(n: int) -> np.ndarray:
     return out
 
 
+def eye(n: int) -> jnp.ndarray:
+    """:func:`eye_table` computed on the device: inside a jitted program
+    it is built from an iota, so the ``[n, W]`` table is never compiled in
+    as a constant."""
+    return set_bit(zeros((n,), n), jnp.arange(n, dtype=jnp.int32))
+
+
 def first_set_bit(bitset: jnp.ndarray) -> jnp.ndarray:
     """Index of the lowest set bit, or -1 if empty.  Batched over leading dims."""
     w = bitset.shape[-1]

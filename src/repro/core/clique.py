@@ -70,10 +70,10 @@ def make_clique_computation(graph: GraphStore,
             size[..., None], pcount[..., None]], axis=-1)
 
     # ------------------------------------------------------------ callbacks
-    def init_frontier():
+    def init_frontier(t):
         # unit cliques {v} with P = N(v) ∩ {u > v}  (canonical seeds)
-        v_bits = jnp.asarray(bitset.eye_table(n))
-        p_bits = ext_mask
+        v_bits = bitset.eye(n)
+        p_bits = t["ext"]
         size = jnp.ones((n,), jnp.int32)
         states = _pack(v_bits, p_bits, size)
         pcount = states[:, 2 * w + 1]

@@ -43,7 +43,7 @@ from __future__ import annotations
 import dataclasses
 import os
 from functools import partial, wraps
-from typing import Optional
+from typing import Any, Optional
 
 import numpy as np
 import jax
@@ -215,6 +215,11 @@ class EngineState:
     result_states: jnp.ndarray    # [k, S]
     result_keys: jnp.ndarray      # [k]
     vpq: VirtualPriorityQueue
+    # this query's device tables, the argument of every program its search
+    # runs: the computation's own, or per-query ones of the same tree,
+    # shapes and dtypes (weighted clique's weights), so the engine's
+    # compiled programs serve them without a new trace
+    tables: Any
     steps: int = 0
     candidates: int = 0
     expanded: int = 0
@@ -300,6 +305,10 @@ class Engine:
         # keeps C), so T blocks can never overflow the default sizing
         self.acc_cap = max(config.overflow_accum or self.T * (self.B + self.M),
                            self.B + self.M)
+        # the computation's default tables; a query may start on its own
+        self.tables = comp.tables
+        self._init = jax.jit(named_program("discovery_init",
+                                           comp.init_frontier))
         self._step = jax.jit(named_program("discovery_step",
                                            self._step_impl))
         self._insert = jax.jit(named_program("discovery_insert",
@@ -338,8 +347,9 @@ class Engine:
     # ------------------------------------------------------------------ step
     def _step_impl(self, pool_states, pool_prio, pool_ub,
                    result_states, result_keys, tables, bound_sync=None):
-        """One super-step.  ``tables`` is the computation's device tables
-        (``comp.tables``), an argument so the graph is never compiled in.
+        """One super-step.  ``tables`` is the query's device tables
+        (``EngineState.tables``), an argument so the graph is never
+        compiled in.
         ``bound_sync`` (None for the single-device engine) maps the local
         result keys to the pruning threshold; the sharded engine passes
         :func:`make_sharded_bound_sync`'s collective so every shard prunes
@@ -656,18 +666,26 @@ class Engine:
                 cat_states[over], cat_prio[over], cat_ub[over])
 
     # ----------------------------------------------------------------- start
-    def start(self) -> EngineState:
-        """Seed the frontier and return a resumable :class:`EngineState`."""
-        with self._span("engine.start"):
-            return self._start_impl()
+    def _place(self, tables):
+        """A query's ``tables`` on the device (the engine's own when
+        None); leaves already there are not copied."""
+        if tables is None:
+            return self.tables
+        return jax.tree.map(jnp.asarray, tables)
 
-    def _start_impl(self) -> EngineState:
+    def start(self, tables=None) -> EngineState:
+        """Seed the frontier and return a resumable :class:`EngineState`
+        that searches with ``tables`` (default: the computation's)."""
+        with self._span("engine.start"):
+            return self._start_impl(self._place(tables))
+
+    def _start_impl(self, tables) -> EngineState:
         cfg, S, C, k = self.cfg, self.S, self.C, self.k
         vpq = VirtualPriorityQueue(
             state_width=S, backend=cfg.spill, spill_dir=cfg.spill_dir,
             obs=self.obs)
 
-        states0, prio0, ub0 = self.comp.init_frontier()
+        states0, prio0, ub0 = self._init(tables)
         n0 = states0.shape[0]
 
         pool_states = jnp.zeros((C, S), jnp.int32)
@@ -691,7 +709,8 @@ class Engine:
             pool_states=pool_states, pool_prio=pool_prio, pool_ub=pool_ub,
             result_states=jnp.zeros((k, S), jnp.int32),
             result_keys=jnp.full((k,), NEG, jnp.int32),
-            vpq=vpq, candidates=int(n0), pool_occupancy=min(int(n0), C))
+            vpq=vpq, tables=tables, candidates=int(n0),
+            pool_occupancy=min(int(n0), C))
 
     # ------------------------------------------------------------------ step
     def step(self, st: EngineState, max_inner: Optional[int] = None
@@ -713,7 +732,7 @@ class Engine:
                      st.result_states, st.result_keys, overflow,
                      stats) = self._step(
                         st.pool_states, st.pool_prio, st.pool_ub,
-                        st.result_states, st.result_keys, self.comp.tables)
+                        st.result_states, st.result_keys, st.tables)
                 with self._span("engine.wait"):
                     stats = jax.tree.map(int, jax.device_get(stats))
                 st.steps += 1
@@ -736,7 +755,7 @@ class Engine:
                  st.result_states, st.result_keys, acc_s, acc_p, acc_u,
                  stats) = self._macro(
                     st.pool_states, st.pool_prio, st.pool_ub,
-                    st.result_states, st.result_keys, self.comp.tables,
+                    st.result_states, st.result_keys, st.tables,
                     np.int32(t_cap), len(st.vpq) > 0,
                     np.int32(st.pool_occupancy))
             with self._span("engine.wait"):
@@ -835,10 +854,13 @@ class Engine:
         mgr.save(st.steps, self._ckpt_arrays(st), blocking=blocking,
                  capture=capture)
 
-    def resume(self, source, step: Optional[int] = None) -> EngineState:
+    def resume(self, source, step: Optional[int] = None,
+               tables=None) -> EngineState:
         """Reconstruct an :class:`EngineState` from a committed checkpoint
         (a directory path or a :class:`CheckpointManager`); its continued
-        run is byte-identical to an uninterrupted one.  Spill files
+        run is byte-identical to an uninterrupted one given the ``tables``
+        the checkpointed query searched with (default: the
+        computation's; a checkpoint holds no tables).  Spill files
         referenced by the checkpoint are re-linked into the live spill
         dir (``cfg.spill_dir`` or a fresh temp dir), so the checkpoint
         remains restorable any number of times."""
@@ -865,7 +887,7 @@ class Engine:
             pool_ub=jnp.asarray(tree["pool_ub"]),
             result_states=jnp.asarray(tree["result_states"]),
             result_keys=jnp.asarray(tree["result_keys"]),
-            vpq=vpq, **extra["scalars"])
+            vpq=vpq, tables=self._place(tables), **extra["scalars"])
 
     # ------------------------------------------------------------------- run
     def run(self, progress_every: int = 0,

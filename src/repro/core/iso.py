@@ -336,26 +336,24 @@ def make_iso_computation(graph: GraphStore,
         acc = acc & ~used
         return jnp.where(d < nq, acc, jnp.zeros((w,), jnp.uint32))
 
-    def init_frontier():
-        # seed = vertices matching slot 0's label class; the vertex
-        # predicate applies here in BOTH filter modes — the frontier is
-        # seeded host-side, and an unfiltered disallowed seed could
-        # complete into a violating result (the post mode only defers
-        # filtering of *candidate* vertices)
-        seed_ok = np.isin(np.asarray(graph.labels), list(classes_o[0]))
-        if allowed_vmask is not None:
-            seed_ok &= allowed_vmask
-        seeds = np.nonzero(seed_ok)[0]
+    # seed = vertices matching slot 0's label class; the vertex predicate
+    # applies here in BOTH filter modes — an unfiltered disallowed seed
+    # could complete into a violating result (the post mode only defers
+    # filtering of *candidate* vertices)
+    seed_ok = np.isin(np.asarray(graph.labels), list(classes_o[0]))
+    if allowed_vmask is not None:
+        seed_ok &= allowed_vmask
+    seeds = np.nonzero(seed_ok)[0].astype(np.int32)
+
+    def init_frontier(t):
         n0 = len(seeds)
-        states = np.full((n0, S), -1, np.int32)
-        states[:, 0] = seeds
-        states[:, nq] = 1                                    # depth
-        sc = graph.degrees[seeds].astype(np.int32)
-        states[:, nq + 1] = sc
-        ub = sc + ub_rest[seeds, 1]
-        prio = 1 * base + ub
-        return (jnp.asarray(states), jnp.asarray(prio, jnp.int32),
-                jnp.asarray(ub, jnp.int32))
+        sc = t["deg"][seeds]
+        states = jnp.full((n0, S), -1, jnp.int32)
+        states = states.at[:, 0].set(seeds)
+        states = states.at[:, nq].set(1)                     # depth
+        states = states.at[:, nq + 1].set(sc)
+        ub = sc + t["ub_rest"][seeds, 1]
+        return states, 1 * base + ub, ub
 
     def score_children(states, t):
         if use_pallas:

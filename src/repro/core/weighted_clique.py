@@ -21,31 +21,41 @@ from .api import NEG, from_pointwise
 from .graph import GraphStore
 
 
+def weight_table(weights) -> np.ndarray:
+    """The ``w`` table of a weighting: ``int32[N]``.  Raises ValueError
+    unless every weight is positive and their sum fits the int32 priority
+    keys."""
+    weights = np.asarray(weights, np.int64)
+    if (weights <= 0).any():
+        raise ValueError("weights must be positive integers")
+    if int(weights.sum()) >= 2 ** 30:
+        raise ValueError("weights must sum below 2**30 (int32 priority keys)")
+    return weights.astype(np.int32)
+
+
 def make_weighted_clique_computation(graph: GraphStore,
                                      weights: np.ndarray):
+    """The weighted-clique computation on ``graph``, with ``weights`` as
+    its default ``w`` table.  Only ``w`` depends on the weighting: an
+    engine built here serves any other weighting of the graph through
+    ``dict(tables, w=weight_table(other))`` (``Engine.start``)."""
     n = graph.n
     w = bitset.num_words(n)
-    weights = np.asarray(weights, np.int32)
-    assert (weights > 0).all()
-    total = int(weights.sum())
-    assert total < 2 ** 30, "int32 priority keys"
     S = 2 * w + 2
 
     adj = jnp.asarray(graph.adj_bits)
     gt = jnp.asarray(bitset.lt_mask_table(n))
-    ext_mask = adj & gt
-    wts = jnp.asarray(weights, jnp.int32)
-    tables = dict(ext=ext_mask, w=wts)
+    tables = dict(ext=adj & gt, w=jnp.asarray(weight_table(weights)))
 
     def _set_weight(bits, wt):
         # weight of a packed bitset via per-word unpack-dot
         return jnp.sum(jnp.where(bitset.to_bool(bits, n), wt, 0))
 
-    def init_frontier():
-        v_bits = jnp.asarray(bitset.eye_table(n))
-        p_bits = ext_mask
-        wv = wts
-        wp = jax.vmap(lambda b: _set_weight(b, wts))(p_bits)
+    def init_frontier(t):
+        v_bits = bitset.eye(n)
+        p_bits = t["ext"]
+        wv = t["w"]
+        wp = jax.vmap(lambda b: _set_weight(b, wv))(p_bits)
         states = jnp.concatenate(
             [bitset.to_i32(v_bits), bitset.to_i32(p_bits),
              wv[:, None], wp[:, None]], axis=-1)

@@ -75,7 +75,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
-from typing import List, Optional
+from typing import Any, List, Optional
 
 import numpy as np
 import jax
@@ -113,6 +113,7 @@ class ShardedEngineState:
     result_keys: jnp.ndarray      # [shards*k]
     vpqs: List[VirtualPriorityQueue]
     pool_occupancy: np.ndarray    # [shards] int64
+    tables: Any                   # the query's tables, replicated (§11)
     steps: int = 0
     candidates: int = 0
     expanded: int = 0
@@ -154,10 +155,11 @@ class ShardedEngine:
                 f"or lower `shards`")
         self.mesh = Mesh(np.asarray(devices[:self.shards]), ("data",))
         # host blocks go straight to their shards (never all to device 0);
-        # graph tables are replicated onto every shard once, not per call
+        # graph tables are replicated onto every shard once per engine, and
+        # a query's own tables (start) place only the leaves they replace
         self._data = NamedSharding(self.mesh, P("data"))
-        self._tables = jax.device_put(comp.tables,
-                                      NamedSharding(self.mesh, P()))
+        self._replicated = NamedSharding(self.mesh, P())
+        self.tables = jax.device_put(comp.tables, self._replicated)
 
         # staleness-tolerant bound exchange (DESIGN.md §14): K inner steps
         # per §4 all-gather.  K is clamped so one K-step segment's overflow
@@ -268,12 +270,20 @@ class ShardedEngine:
                 donate_argnums=donatable_pool_argnums())
 
     # ----------------------------------------------------------------- start
-    def start(self) -> ShardedEngineState:
-        """Seed-partition the frontier and return a resumable state."""
-        with self._span("engine.start"):
-            return self._start_impl()
+    def _place(self, tables):
+        """A query's ``tables`` replicated over the mesh (the engine's own
+        when None); leaves already placed there are not copied."""
+        if tables is None:
+            return self.tables
+        return jax.device_put(tables, self._replicated)
 
-    def _start_impl(self) -> ShardedEngineState:
+    def start(self, tables=None) -> ShardedEngineState:
+        """Seed-partition the frontier and return a resumable state that
+        searches with ``tables`` (default: the computation's)."""
+        with self._span("engine.start"):
+            return self._start_impl(self._place(tables))
+
+    def _start_impl(self, tables) -> ShardedEngineState:
         cfg, S, C, k, shards = self.cfg, self.S, self.C, self.k, self.shards
         vpqs = []
         for i in range(shards):
@@ -284,7 +294,7 @@ class ShardedEngine:
                 obs=self.obs))
 
         states0, prio0, ub0 = (np.asarray(a) for a in
-                               self.comp.init_frontier())
+                               self._eng._init(tables))
         n0 = states0.shape[0]
 
         pool_states = np.zeros((shards, C, S), np.int32)
@@ -313,7 +323,8 @@ class ShardedEngine:
         return ShardedEngineState(
             pool_states=pool_states, pool_prio=pool_prio, pool_ub=pool_ub,
             result_states=result_states, result_keys=result_keys,
-            vpqs=vpqs, pool_occupancy=occ, candidates=int(n0))
+            vpqs=vpqs, pool_occupancy=occ, tables=tables,
+            candidates=int(n0))
 
     # ------------------------------------------------------------------ step
     def step(self, st: ShardedEngineState,
@@ -332,7 +343,7 @@ class ShardedEngine:
                      st.result_states, st.result_keys, overflow,
                      stats) = self._step_sharded(
                         st.pool_states, st.pool_prio, st.pool_ub,
-                        st.result_states, st.result_keys, self._tables)
+                        st.result_states, st.result_keys, st.tables)
                 with self._span("engine.wait"):
                     stats = jax.device_get(stats)  # each value: [shards]
 
@@ -363,7 +374,7 @@ class ShardedEngine:
                  st.result_states, st.result_keys, acc_s, acc_p, acc_u,
                  stats) = self._macro_sharded(
                     st.pool_states, st.pool_prio, st.pool_ub,
-                    st.result_states, st.result_keys, self._tables,
+                    st.result_states, st.result_keys, st.tables,
                     np.int32(t_cap),
                     np.asarray([len(v) > 0 for v in st.vpqs]),
                     st.pool_occupancy.astype(np.int32))
@@ -548,11 +559,12 @@ class ShardedEngine:
                     result_keys=st.result_keys)
         mgr.save(st.steps, tree, blocking=blocking, capture=capture)
 
-    def resume(self, source,
-               step: Optional[int] = None) -> ShardedEngineState:
+    def resume(self, source, step: Optional[int] = None,
+               tables=None) -> ShardedEngineState:
         """Rebuild a :class:`ShardedEngineState` whose continued run is
-        byte-identical to an uninterrupted one.  The checkpoint must have
-        been written at the same shard count."""
+        byte-identical to an uninterrupted one, given the query's
+        ``tables`` as in :meth:`repro.core.engine.Engine.resume`.  The
+        checkpoint must have been written at the same shard count."""
         from repro.checkpoint.manager import CheckpointManager
         mgr = (source if isinstance(source, CheckpointManager)
                else CheckpointManager(source, obs=self.obs))
@@ -586,6 +598,7 @@ class ShardedEngine:
                                            "pool_ub", "result_states",
                                            "result_keys")}, self._data)
         return ShardedEngineState(vpqs=vpqs, pool_occupancy=occ,
+                                  tables=self._place(tables),
                                   **arrays, **scalars)
 
     # ------------------------------------------------------------------- run

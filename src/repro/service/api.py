@@ -30,6 +30,11 @@ from .cache import ResultCache
 
 WORKLOADS = ("clique", "weighted-clique", "iso", "pattern")
 
+# request fields that reach the engine only as per-query device tables
+# (request_tables), by workload: they stay in the result-cache key but
+# leave the engine-reuse key, so one compiled engine serves every value
+TABLE_FIELDS = {"weighted-clique": ("weights",)}
+
 
 class ValidationError(ValueError):
     """A malformed :class:`DiscoveryRequest` (rejected before execution)."""
@@ -73,7 +78,7 @@ class DiscoveryRequest:
     step_budget: int = 100_000        # max engine super-steps for this query
     candidate_budget: Optional[int] = None  # max subgraphs materialized
     # workload-specific parameters
-    weights: Optional[Tuple[int, ...]] = None             # weighted-clique
+    weights: Optional[Tuple[int, ...]] = None  # weighted-clique; TABLE_FIELDS
     q_edges: Optional[Tuple[Tuple[int, int], ...]] = None  # iso query graph
     q_labels: Optional[Tuple[int, ...]] = None             # iso query labels
     induced: bool = True                                   # iso semantics
@@ -477,3 +482,13 @@ def compile_request(req: DiscoveryRequest, registry: GraphRegistry,
 
     return CompiledQuery(request=req, graph=g, kind="engine",
                          comp=comp, engine_cfg=cfg)
+
+
+def request_tables(req: DiscoveryRequest, tables):
+    """The device tables ``req`` searches with, over an engine's own
+    ``tables``: the request's :data:`TABLE_FIELDS` replace the leaves they
+    feed, and every other leaf is the engine's, already placed."""
+    if req.workload == "weighted-clique":
+        from repro.core.weighted_clique import weight_table
+        return dict(tables, w=weight_table(req.weights))
+    return tables

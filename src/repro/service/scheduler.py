@@ -27,8 +27,9 @@ from repro.core.graph import GraphStore
 from repro.obs import NOOP, watch_jax_compiles
 from repro.runtime.fault_tolerance import StragglerMonitor
 
-from .api import (DiscoveryRequest, DiscoveryResponse, GraphRegistry,
-                  ValidationError, compile_request)
+from .api import (TABLE_FIELDS, DiscoveryRequest, DiscoveryResponse,
+                  GraphRegistry, ValidationError, compile_request,
+                  request_tables)
 from .cache import ResultCache, make_cache_key
 
 
@@ -36,13 +37,14 @@ from .cache import ResultCache, make_cache_key
 class EngineQueryTask:
     """One queue-driven query (clique / weighted-clique / iso) being stepped.
 
-    ``engine`` may be shared across tasks with the identical compiled spec
-    (the service's engine cache): all per-query search state lives in
-    ``self.state``, so a shared engine only shares the jitted step —
-    avoiding an XLA re-trace per request.
+    ``engine`` may be shared across tasks with the same engine key (the
+    service's engine cache): all per-query search state lives in
+    ``self.state``, its device ``tables`` included, so a shared engine
+    only shares the jitted programs — avoiding an XLA re-trace per
+    request.
     """
 
-    def __init__(self, request: DiscoveryRequest, engine: Engine,
+    def __init__(self, request: DiscoveryRequest, engine: Engine, tables,
                  obs=NOOP, task_id: int = 0):
         self.request = request
         self.comp = engine.comp
@@ -76,9 +78,9 @@ class EngineQueryTask:
         self.state = None
         if request.resume and self._mgr is not None and \
                 self._mgr.latest_step() is not None:
-            self.state = engine.resume(self._mgr)
+            self.state = engine.resume(self._mgr, tables=tables)
         if self.state is None:
-            self.state = engine.start()
+            self.state = engine.start(tables)
         self.steps_at_admission = self.state.steps
         self._last_ckpt = self.state.steps
         # per-query slow-step watchdog: EMA step-time monitor, flagged
@@ -296,10 +298,11 @@ class DiscoveryService:
         self.registry = registry or GraphRegistry()
         self.cache = cache or ResultCache()
         self.scheduler = QueryScheduler(slice_steps=slice_steps)
-        # compiled-engine reuse: identical specs (same cache key) share one
-        # Engine and therefore one XLA trace of the super-step; all search
-        # state is per-task (EngineState), so sharing is safe even within
-        # a batch.  LRU-bounded; TTL is irrelevant for compiled code.
+        # compiled-engine reuse: requests with one engine key (_make_task)
+        # share one Engine and therefore one XLA trace of its programs; all
+        # search state, per-query tables included, is per-task
+        # (EngineState), so sharing is safe even within a batch.
+        # LRU-bounded; TTL is irrelevant for compiled code.
         self._engines = ResultCache(capacity=engine_cache_size,
                                     ttl_s=float("inf"))
         self._tasks_admitted = 0
@@ -418,19 +421,22 @@ class DiscoveryService:
     def _make_task(self, req: DiscoveryRequest, graph: GraphStore):
         if req.workload == "pattern":
             return PatternQueryTask(req, graph, obs=self.obs)
-        # the engine key covers only what shapes the compiled step: budgets
-        # are enforced per-task (so they're dropped from the spec), while
-        # use_pallas/interpret/steps_per_sync/sync_every change the
-        # compiled step without changing complete-run results (so they're
-        # added back — all four are deliberately absent from the
+        # the engine key covers only what shapes the compiled programs:
+        # budgets are enforced per-task, and the workload's TABLE_FIELDS
+        # (weighted clique's weights) reach the engine only as per-query
+        # device tables (request_tables), so all of them are dropped from
+        # the spec; use_pallas/interpret/steps_per_sync/sync_every change
+        # the compiled step without changing complete-run results (so
+        # they're added back — all four are deliberately absent from the
         # result-cache key; shards is already in the spec).  The checkpoint
         # knobs join them: they ride EngineConfig (Engine.run reads them),
         # so tasks sharing an engine must share its checkpoint policy —
         # and two queries writing different checkpoint_dirs must not share
         # one engine object (DESIGN.md §15).
         engine_spec = req.canonical_spec()
-        engine_spec.pop("step_budget", None)
-        engine_spec.pop("candidate_budget", None)
+        for name in ("step_budget", "candidate_budget",
+                     *TABLE_FIELDS.get(req.workload, ())):
+            engine_spec.pop(name)
         engine_spec["use_pallas"] = req.use_pallas
         engine_spec["interpret"] = req.interpret
         engine_spec["steps_per_sync"] = req.steps_per_sync
@@ -446,8 +452,8 @@ class DiscoveryService:
             self._m_engine_builds.inc()
             self._engines.put(engine_key, engine)
         self._tasks_admitted += 1
-        return EngineQueryTask(req, engine, obs=self.obs,
-                               task_id=self._tasks_admitted)
+        return EngineQueryTask(req, engine, request_tables(req, engine.tables),
+                               obs=self.obs, task_id=self._tasks_admitted)
 
     def _build_engine(self, req: DiscoveryRequest, graph: GraphStore):
         compiled = compile_request(req, self.registry, graph=graph)

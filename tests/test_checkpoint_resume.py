@@ -31,6 +31,7 @@ from repro.core.clique import make_clique_computation
 from repro.core.engine import Engine, EngineConfig
 from repro.data.synthetic_graphs import densifying_graph
 from repro.distributed import ShardedEngine
+from repro.obs import Observability
 from repro.service import (DiscoveryRequest, DiscoveryService,
                            ValidationError)
 
@@ -188,6 +189,56 @@ def test_resumed_query_honors_budget_and_step_accounting(tmp_path):
     assert done.result_keys == oracle.result_keys
     assert done.results == oracle.results
     assert "straggler_steps" in done.stats
+
+
+def test_resume_through_an_engine_another_weighting_built(tmp_path):
+    """A checkpointed weighted-clique query resumed through a shared
+    engine, one that another weighting built, continues byte-identically;
+    a task of that other weighting in the same batch keeps its own
+    tables (weights are per-query tables, not part of the engine key)."""
+    g = densifying_graph(64, 256, seed=5)
+    rng = np.random.default_rng(11)
+    w1, w2 = (tuple(int(x) for x in rng.integers(1, 60, g.n))
+              for _ in range(2))
+    ck = str(tmp_path / "ck")
+    base = dict(graph="g", workload="weighted-clique", k=3, batch=8,
+                pool_capacity=64, use_cache=False)
+
+    def service():
+        svc = DiscoveryService(observability=Observability())
+        svc.register_graph("g", g)
+        return svc
+
+    def answer(resp):
+        assert resp.status == "ok", resp.error
+        stats = dict(resp.stats)
+        stats.pop("straggler_steps")
+        return json.dumps(dict(keys=resp.result_keys, results=resp.results,
+                               stats=stats, terminated=resp.terminated))
+
+    oracle1 = service().query(DiscoveryRequest(**base, weights=w1))
+    oracle2 = service().query(DiscoveryRequest(**base, weights=w2))
+    # cut early, while the resumed search still has most of its
+    # candidates to score with the weights
+    part = service().query(DiscoveryRequest(
+        **base, weights=w1, step_budget=2, checkpoint_every=2,
+        checkpoint_dir=ck))
+    assert part.stats["candidates"] < oracle1.stats["candidates"]
+    assert part.terminated == "step_budget"
+
+    # the restart reads the checkpoint and writes none (checkpoint_every
+    # 0), so every request below has one engine key
+    svc = service()
+    svc.query(DiscoveryRequest(**base, weights=w2, checkpoint_dir=ck))
+    resumed, other = svc.serve([
+        DiscoveryRequest(**base, weights=w1, checkpoint_dir=ck,
+                         resume=True),
+        DiscoveryRequest(**base, weights=w2, checkpoint_dir=ck)])
+    assert svc.obs.metrics.get("service_engine_builds_total").value == 1
+    assert answer(resumed) == answer(oracle1)
+    assert answer(other) == answer(oracle2)
+    assert svc.engine_steps_total == \
+        2 * oracle2.stats["steps"] + oracle1.stats["steps"] - 2
 
 
 def test_resume_with_empty_checkpoint_dir_starts_fresh(tmp_path):

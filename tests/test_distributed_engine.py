@@ -236,3 +236,55 @@ def test_sharded_parity_inprocess_multi_device(tmp_path, shards):
     for i in range(shards):   # leak-free: every run file closed
         sub = tmp_path / f"shard{i}"
         assert not sub.exists() or list(sub.iterdir()) == []
+
+
+# ---------------------------------- one sharded engine across weightings
+_WEIGHTINGS_PROG = """
+    import json
+    import numpy as np
+    import jax
+    from repro.core.weighted_clique import brute_force_max_weight_clique
+    from repro.data.synthetic_graphs import densifying_graph
+    from repro.obs import Observability
+    from repro.service import DiscoveryRequest, DiscoveryService
+
+    assert len(jax.devices()) == 4
+    g = densifying_graph(50, 180, seed=3)
+    rng = np.random.default_rng(7)
+    ws = [tuple(int(x) for x in rng.integers(1, 50, g.n)) for _ in range(3)]
+    reqs = [DiscoveryRequest(graph="g", workload="weighted-clique", k=2,
+                             weights=w, batch=8, pool_capacity=64,
+                             shards=4, sync_every=4, observe=True)
+            for w in ws]
+
+    def answer(resp):
+        assert resp.status == "ok", resp.error
+        return json.dumps(dict(keys=resp.result_keys, results=resp.results,
+                               steps=resp.stats["steps"],
+                               candidates=resp.stats["candidates"],
+                               host_syncs=resp.stats["host_syncs"]))
+
+    svc = DiscoveryService(observability=Observability())
+    svc.register_graph("g", g)
+    m = svc.obs.metrics
+    resps = svc.serve(reqs[:2])            # two weightings in one batch
+    traced = m.get("jax_trace_seconds_total").value
+    resps += svc.serve(reqs[2:])           # and one in the next
+    assert m.get("jax_trace_seconds_total").value == traced
+    assert m.get("service_engine_builds_total").value == 1
+    for w, req, resp in zip(ws, reqs, resps):
+        fresh = DiscoveryService()         # an engine of its own
+        fresh.register_graph("g", g)
+        assert answer(resp) == answer(fresh.query(req))
+        best, _ = brute_force_max_weight_clique(g, np.asarray(w))
+        assert resp.result_keys[0] == best
+    print("WEIGHTINGS-SHARDED-OK", flush=True)
+"""
+
+
+def test_sharded_engine_serves_every_weighting():
+    """One 4-shard engine serves three weightings, in one batch and the
+    next, each exactly as a freshly built 4-shard engine would."""
+    res = _run_forced(_WEIGHTINGS_PROG, devices=4)
+    assert "WEIGHTINGS-SHARDED-OK" in res.stdout, \
+        (res.stdout, res.stderr[-3000:])

@@ -82,7 +82,7 @@ def random_graph(draw):
 def test_clique_ub_anti_monotone(g):
     """API contract: ub(child) <= ub(parent) and result_key <= ub."""
     comp = make_clique_computation(g)
-    states, prio, ub = comp.init_frontier()
+    states, prio, ub = comp.init_frontier(comp.tables)
     rk = comp.result_key(states, comp.tables)
     assert bool(jnp.all(rk <= ub))
     child_prio, child_ub = comp.score_children(states, comp.tables)
@@ -96,7 +96,7 @@ def test_clique_ub_anti_monotone(g):
 def test_clique_expansion_canonical(g):
     """Children only add vertices greater than every parent vertex."""
     comp = make_clique_computation(g)
-    states, _, _ = comp.init_frontier()
+    states, _, _ = comp.init_frontier(comp.tables)
     child_prio, _ = comp.score_children(states, comp.tables)
     valid = np.asarray(child_prio > jnp.iinfo(jnp.int32).min)
     for v in range(g.n):             # seed {v} may only expand to u > v

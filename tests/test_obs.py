@@ -581,17 +581,19 @@ def test_engine_builds_count_cache_misses(social):
     builds = svc.obs.metrics.get("service_engine_builds_total")
     rng = np.random.default_rng(0)
 
-    def req(weights, **kw):
+    def req(weights, k=3, **kw):
         return DiscoveryRequest(graph="social", workload="weighted-clique",
-                                k=3, weights=weights, observe=True, **kw)
+                                k=k, weights=weights, observe=True, **kw)
 
     w1, w2 = (tuple(int(x) for x in rng.integers(1, 100, social.n))
               for _ in range(2))
     svc.serve([req(w1), req(w2)])
-    assert builds.value == 2                      # one per weighting
+    assert builds.value == 1            # weights are per-query tables
     svc.serve([req(w1, use_cache=False)])         # engine reused
-    assert builds.value == 2
+    assert builds.value == 1
     svc.serve([req(w1)])                          # result-cache hit
+    assert builds.value == 1
+    svc.serve([req(w2, k=4)])           # k shapes the program: a new engine
     assert builds.value == 2
 
 
@@ -616,6 +618,9 @@ def test_engine_programs_are_named(clique_setup):
     args = pool + (st.result_states, st.result_keys, comp.tables)
     new = tuple(a[:4] for a in pool)
     lowered = {
+        "jit_discovery_init": (eng1._init.lower(comp.tables),
+                               jax.jit(comp.init_frontier).lower(
+                                   comp.tables)),
         "jit_discovery_step": (eng1._step.lower(*args),
                                jax.jit(eng1._step_impl).lower(*args)),
         "jit_discovery_insert": (eng1._insert.lower(*pool, *new),
@@ -649,7 +654,7 @@ def test_sharded_programs_are_named_on_four_devices():
             eng = ShardedEngine(comp, dataclasses.replace(cfg, **kw))
             st = eng.start()
             args = (st.pool_states, st.pool_prio, st.pool_ub,
-                    st.result_states, st.result_keys, eng._tables)
+                    st.result_states, st.result_keys, eng.tables)
             if eng.T == 1:
                 lowered = [eng._step_sharded.lower(*args),
                            eng._insert_sharded.lower(*args[:3], *args[:3])]
