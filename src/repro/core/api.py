@@ -98,25 +98,36 @@ class SubgraphComputation:
 def from_pointwise(name: str,
                    state_width: int,
                    num_actions: int,
-                   init_frontier,    # (tables) -> (states, prio, ub)
-                   expandable,       # (state [S], action, tables) -> bool
-                   child_priority,   # (state [S], action, tables) -> int32
-                   child_ub,         # (state [S], action, tables) -> int32
-                   materialize_one,  # (state [S], action, tables) -> [S]
-                   relevant,         # (state [S], tables) -> bool
-                   result_key_one,   # (state [S], tables) -> int32
-                   upper_bound_one,  # (state [S], tables) -> int32
-                   describe=None, tables=()) -> SubgraphComputation:
+                   init_frontier,        # (tables) -> (states, prio, ub)
+                   expandable=None,      # (state [S], action, tables) -> bool
+                   child_priority=None,  # (state [S], action, tables) -> int32
+                   child_ub=None,        # (state [S], action, tables) -> int32
+                   *,
+                   materialize_one,      # (state [S], action, tables) -> [S]
+                   relevant,             # (state [S], tables) -> bool
+                   result_key_one,       # (state [S], tables) -> int32
+                   upper_bound_one,      # (state [S], tables) -> int32
+                   describe=None, tables=(),
+                   score_children=None) -> SubgraphComputation:
     """Succinct per-subgraph API (the paper's Listing-1 style), vmapped.
 
     Users write scalar functions over a single state (plus the shared
     ``tables``); this adapter builds the batched computation via
-    ``jax.vmap``.  The fused batched path (e.g. :mod:`repro.core.clique`)
-    is preferred for hot computations.
+    ``jax.vmap``.  A batched ``score_children`` (the field of
+    :class:`SubgraphComputation`) replaces the three scalar scorers
+    ``expandable``, ``child_priority`` and ``child_ub``: child scoring is
+    the hot loop, and a computation may fuse it over the whole ``[B, A]``
+    grid (:mod:`repro.core.weighted_clique`) while keeping the rest
+    scalar.  The fused batched path (e.g. :mod:`repro.core.clique`) is
+    preferred for hot computations.
     """
+    if any((f is None) != (score_children is not None)
+           for f in (expandable, child_priority, child_ub)):
+        raise ValueError(f"{name}: give either expandable, child_priority "
+                         "and child_ub, or a batched score_children")
     actions = jnp.arange(num_actions, dtype=jnp.int32)
 
-    def score_children(states, t):
+    def pointwise_score_children(states, t):
         def per_state(s):
             def per_action(a):
                 ok = expandable(s, a, t)
@@ -138,6 +149,7 @@ def from_pointwise(name: str,
 
     return SubgraphComputation(
         name=name, state_width=state_width, num_actions=num_actions,
-        init_frontier=init_frontier, score_children=score_children,
+        init_frontier=init_frontier,
+        score_children=score_children or pointwise_score_children,
         materialize=materialize, result_key=result_key,
         upper_bound=upper_bound, describe=describe, tables=tables)

@@ -2,9 +2,12 @@
 per-subgraph API (:func:`repro.core.api.from_pointwise`), the Python analog
 of the paper's Listing 1.
 
-Demonstrates the Table-1 generality claim: a new top-k computation is four
-scalar functions (expandable / priority / relevant+result / dominated); the
-engine, batching, pruning, and VPQ come for free.
+Demonstrates the Table-1 generality claim: a new top-k computation is a
+few scalar functions (relevant+result / dominated / materialize); the
+engine, batching, pruning, and VPQ come for free.  Child scoring, the hot
+loop, is the one batched function (:func:`weighted_counts`): the weighted
+popcount ``w(P_b ∩ ext[a])`` of the whole ``[B, N]`` grid is one exact
+integer matrix product on the MXU.
 
 State layout (``S = 2W + 2``): V bitset, P bitset, weight(V), weight(P) —
 the dominance bound ``w(V) + w(P)`` generalizes the CP cardinality bound.
@@ -19,6 +22,58 @@ import jax.numpy as jnp
 from . import bitset
 from .api import NEG, from_pointwise
 from .graph import GraphStore
+
+# Weights enter the matrix product as unsigned 8-bit digits, each exact in
+# bfloat16; four of them cover every weight below 2**30 (weight_table).
+_DIGIT_BITS = 8
+_NUM_DIGITS = 4
+_DIGIT_MAX = 2 ** _DIGIT_BITS - 1
+# float32 holds every integer below 2**24 exactly
+_F32_EXACT = 2 ** 24
+
+
+def contraction_chunks(num_words: int) -> list:
+    """Word ranges ``[lo, hi)`` that split a weighted popcount over
+    ``num_words`` bitset words into matrix products whose float32 partial
+    sums stay exact: a chunk of ``L`` bits sums at most ``L`` digits of
+    ``_DIGIT_MAX``, which must stay below ``2**24``.  One range up to
+    65,792 vertices."""
+    step = (_F32_EXACT - 1) // (bitset.WORD_BITS * _DIGIT_MAX)
+    return [(lo, min(lo + step, num_words))
+            for lo in range(0, num_words, step)]
+
+
+def weighted_counts(p_bits: jnp.ndarray, ext: jnp.ndarray,
+                    wt: jnp.ndarray) -> jnp.ndarray:
+    """``out[b, a] = w(P_b ∩ ext[a])`` = Σ_u w[u]·[u ∈ P_b]·[u ∈ ext[a]],
+    exactly, for ``p_bits`` ``[B, W]``, ``ext`` ``[N, W]`` (uint32 bitsets)
+    and ``wt`` ``int32[N]`` summing below 2**30.
+
+    The 0/1 ``P`` rows, scaled by each 8-bit digit of ``w``, are stacked
+    into ``[D·B, 32W]`` bfloat16 rows and contracted with the unpacked 0/1
+    ``ext`` in one ``dot_general`` per chunk of :func:`contraction_chunks`,
+    accumulating in float32; every operand and partial sum is an integer
+    its dtype holds exactly, so the digits recombine in int32 to the exact
+    sum."""
+    b, w = p_bits.shape
+    n = ext.shape[0]
+    width = w * bitset.WORD_BITS
+    in_p = bitset.to_bool(p_bits, width)                     # [B, 32W]
+    wt = jnp.pad(wt, (0, width - wt.shape[0]))
+    shifts = _DIGIT_BITS * jnp.arange(_NUM_DIGITS, dtype=jnp.int32)
+    digits = (wt[None, :] >> shifts[:, None]) & _DIGIT_MAX    # [D, 32W]
+    rows = jnp.where(in_p[None], digits[:, None, :], 0)
+    rows = rows.reshape(_NUM_DIGITS * b, width).astype(jnp.bfloat16)
+    acc = jnp.zeros((_NUM_DIGITS * b, n), jnp.int32)
+    for lo, hi in contraction_chunks(w):
+        cols = bitset.to_bool(ext[:, lo:hi], (hi - lo) * bitset.WORD_BITS)
+        part = jax.lax.dot_general(
+            rows[:, lo * bitset.WORD_BITS:hi * bitset.WORD_BITS],
+            cols.astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        acc = acc + part.astype(jnp.int32)
+    acc = acc.reshape(_NUM_DIGITS, b, n)
+    return sum(acc[k] << (_DIGIT_BITS * k) for k in range(_NUM_DIGITS))
 
 
 def weight_table(weights) -> np.ndarray:
@@ -61,22 +116,18 @@ def make_weighted_clique_computation(graph: GraphStore,
              wv[:, None], wp[:, None]], axis=-1)
         return states, wv + wp, wv + wp
 
-    # ----- the paper's five user functions, scalar over one state --------
+    # ----- the paper's user functions; scoring batched over [B, N] -----
     def _unpack(s):
         return (bitset.to_u32(s[:w]), bitset.to_u32(s[w:2 * w]),
                 s[2 * w], s[2 * w + 1])
 
-    def expandable(s, a, t):
-        _, p, _, _ = _unpack(s)
-        return bitset.get_bit(p[None], jnp.asarray([a]))[0]
-
-    def child_priority(s, a, t):
-        _, p, wv, _ = _unpack(s)
-        new_p = p & t["ext"][a]
-        return wv + t["w"][a] + _set_weight(new_p, t["w"])
-
-    def child_ub(s, a, t):       # same space: weight is the result metric
-        return child_priority(s, a, t)
+    def score_children(states, t):
+        # prio = ub = w(V) + w[a] + w(P ∩ ext[a]); NEG where a ∉ P
+        p_bits = bitset.to_u32(states[:, w:2 * w])
+        score = (states[:, 2 * w, None] + t["w"][None, :]
+                 + weighted_counts(p_bits, t["ext"], t["w"]))
+        score = jnp.where(bitset.to_bool(p_bits, n), score, NEG)
+        return score, score
 
     def materialize_one(s, a, t):
         v, p, wv, _ = _unpack(s)
@@ -102,8 +153,7 @@ def make_weighted_clique_computation(graph: GraphStore,
 
     return from_pointwise(
         name="weighted-clique", state_width=S, num_actions=n,
-        init_frontier=init_frontier, expandable=expandable,
-        child_priority=child_priority, child_ub=child_ub,
+        init_frontier=init_frontier, score_children=score_children,
         materialize_one=materialize_one, relevant=relevant,
         result_key_one=result_key_one, upper_bound_one=upper_bound_one,
         describe=describe, tables=tables)

@@ -137,3 +137,19 @@ def test_step_sorts_stay_narrow(one_chip, clique_engine, program):
         lowered = clique_engine._macro.lower(
             *pool, tables, np.int32(16), False, np.int32(0))
     assert _widest_sort(lowered.as_text()) <= 3
+
+
+def test_weighted_clique_step_scores_on_the_mxu(one_chip):
+    """One weighted-clique super-step at the benchmark's width (N=4096,
+    B=64): child scoring compiles to one convolution over the four digit
+    rows of each state, and no fusion spans the ``[B, N, W]`` grid."""
+    from repro.core.weighted_clique import make_weighted_clique_computation
+    n, b = 4096, 64
+    g = planted_clique_graph(n=n, m=10 * n, clique_size=12)
+    eng = Engine(make_weighted_clique_computation(g, np.ones(n, int)),
+                 EngineConfig(k=10, batch=b))
+    pool, tables = _step_args(eng, one_chip)
+    text = eng._step.lower(*pool, tables).compile().as_text()
+    convs = re.findall(r"= (\w+)\[([\d,]+)\]\S* convolution\(", text)
+    assert convs == [("f32", f"{4 * b},{n}")], convs
+    assert f"[{b},{n},{n // 32}]" not in text
